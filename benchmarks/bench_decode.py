@@ -33,11 +33,11 @@ forward; each ``spec_off_bs*`` row is the identical-workload baseline):
     overhead until the draft gets its own jitted cache (ROADMAP).
 
 Model-parallel rows (``tp{N}_bs4``): the fused decode scenario sharded
-over a forced 8-device CPU mesh at TP in {1, 2, 4, 8} — each degree runs
-in a fresh subprocess (``--model-parallel N`` on this module) because
-``--xla_force_host_platform_device_count`` must be set before the jax
-backend initializes, and forcing it in the parent would distort the
-single-device rows. On CPU smoke these rows measure the *sharding seam
+over a forced 8-device CPU mesh at TP in {1, 2, 4, 8} — on CPU each
+degree runs in a fresh subprocess (``--model-parallel N`` on this
+module) because ``--xla_force_host_platform_device_count`` must be set
+before the jax backend initializes, and forcing it in the parent would
+distort the single-device rows. On CPU smoke these rows measure the *sharding seam
 overhead* (GSPMD psum/all-gather per step on one physical socket), not a
 speedup: smoke-scale math is far below the collective launch cost, so
 tok/s drops as TP rises. The row the TPU deployment cares about is that
@@ -141,8 +141,9 @@ def _measure_spec(cfg, params, *, speculate, spec_depth: int,
 
 
 def _measure_model_parallel(tp: int) -> dict:
-    """One TP row, meant to run inside a subprocess with the device count
-    already forced (see run()). Token parity with TP=1 is pinned by
+    """One TP row, run by ``run_model_parallel_rows``: in a child process
+    with the CPU device count forced, or in this process on a chip host.
+    Token parity with TP=1 is pinned by
     tests/test_sharded_serving.py; this row records the throughput cost of
     the sharding seam at each degree."""
     from repro.launch.mesh import make_local_mesh
@@ -157,6 +158,7 @@ def _measure_model_parallel(tp: int) -> dict:
 
 def _run_tp_rows(results: dict) -> None:
     for tp, r in run_model_parallel_rows("benchmarks.bench_decode",
+                                         _measure_model_parallel,
                                          TP_DEGREES, TP_FORCED_DEVICES):
         results["runs"][f"tp{tp}_bs4"] = r
         emit(f"bench_decode/tp{tp}_bs4", r["decode_time_s"] * 1e6,
@@ -213,7 +215,7 @@ def run(spec_depth: int = 8):
         emit(f"bench_decode/speedup_spec_ngram_{bs_tag}", 0,
              f"{results['runs'][f'speedup_spec_ngram_{bs_tag}']}"
              "x_ngram_over_plain")
-    # --- model-parallel rows: one subprocess per TP degree (forced mesh) ---
+    # --- model-parallel rows (benchmarks.common.run_model_parallel_rows) ---
     _run_tp_rows(results)
     write_bench_json(OUT_PATH, results)
 

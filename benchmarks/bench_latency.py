@@ -40,8 +40,8 @@ Scenarios (smoke-scale honesty notes inline):
     contract (engine "Failure semantics") priced as a benchmark row.
   * ``chunked_prefill_tp{N}`` — the chunked scenario on a model-axis-
     sharded engine (forced 8-device CPU mesh, one subprocess per degree
-    via ``--model-parallel N`` so the device-count flag lands before jax
-    initializes). On one physical socket these price the per-step GSPMD
+    on CPU via ``--model-parallel N`` so the device-count flag lands
+    before jax initializes). On one physical socket these price the per-step GSPMD
     collective seam in the TTFT/TPOT tails — the scheduler behaves
     identically (host-global policy), so any tail shift is pure seam.
   * ``shared_prefix_nocache`` / ``shared_prefix_cache`` — the
@@ -262,8 +262,9 @@ def _measure_telemetry_overhead(cfg, params) -> dict:
 
 
 def _measure_model_parallel(tp: int) -> dict:
-    """chunked_prefill scenario on a model-axis-sharded engine; runs in a
-    subprocess with the forced device count (see _run_tp_rows)."""
+    """chunked_prefill scenario on a model-axis-sharded engine; on CPU it
+    runs in a child process with the forced device count (see
+    benchmarks.common.run_model_parallel_rows)."""
     from repro.launch.mesh import make_local_mesh
     cfg = get_config("qwen1.5-0.5b", reduced=True)
     params = LM(cfg).init(jax.random.PRNGKey(0))
@@ -276,6 +277,7 @@ def _measure_model_parallel(tp: int) -> dict:
 
 def _run_tp_rows(results: dict) -> None:
     for tp, r in run_model_parallel_rows("benchmarks.bench_latency",
+                                         _measure_model_parallel,
                                          TP_DEGREES, TP_FORCED_DEVICES):
         results["runs"][f"chunked_prefill_tp{tp}"] = r
         emit(f"bench_latency/chunked_prefill_tp{tp}",

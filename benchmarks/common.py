@@ -62,17 +62,24 @@ def write_bench_json(path: str, results: Any) -> None:
         json.dump({"meta": bench_meta(), "results": results}, f, indent=2)
 
 
-def run_model_parallel_rows(module: str, degrees, forced_devices: int):
-    """Yield (tp, row_dict) for each TP degree by re-running ``module``
-    (a ``python -m``-able benchmark) in a subprocess with the CPU device
-    count forced. ``--xla_force_host_platform_device_count`` must land
-    before the jax backend initializes, and forcing it in the parent
-    would distort its single-device rows — hence one subprocess per
-    degree, each printing a single JSON line (its module's
-    ``--model-parallel`` branch). Failed degrees are reported to stderr
-    and skipped so a bench sweep never dies on the sharded rows."""
-    import json
-    import os
+def run_model_parallel_rows(module: str, measure: Callable[[int], dict],
+                            degrees, forced_devices: int):
+    """Yield (tp, row_dict) for each TP degree; a failed degree raises.
+
+    On the CPU backend each degree re-runs ``module`` (a ``python -m``-able
+    benchmark) in a child process with the host device count forced:
+    ``--xla_force_host_platform_device_count`` must land before the jax
+    backend initializes, and forcing it here would distort this process's
+    single-device rows. Each child prints one JSON line (its module's
+    ``--model-parallel`` branch). On any other backend this process
+    already holds the chips and a child could not reach them, so
+    ``measure(tp)`` runs here over ``jax.devices()``; degrees wider than
+    the device count are left out."""
+    if jax.default_backend() != "cpu":
+        for tp in degrees:
+            if tp <= jax.device_count():
+                yield tp, measure(tp)
+        return
     import subprocess
     import sys
 
@@ -86,7 +93,7 @@ def run_model_parallel_rows(module: str, degrees, forced_devices: int):
             capture_output=True, text=True, env=env)
         lines = proc.stdout.splitlines()
         if proc.returncode != 0 or not lines:
-            print(f"# {module} tp{tp} subprocess failed:\n"
-                  f"{proc.stderr[-2000:]}", file=sys.stderr)
-            continue
+            raise RuntimeError(f"{module} tp{tp} child failed "
+                               f"(exit {proc.returncode}):\n"
+                               f"{proc.stderr[-2000:]}")
         yield tp, json.loads(lines[-1])

@@ -14,6 +14,7 @@ from benchmarks import (bench_decode, bench_latency, fig6_serving,
                         fig11_gemm, fig13_collectives, table2_frameworks,
                         table3_techniques, table5_modulewise,
                         table8_flashattention, table9_finetuning)
+from repro.launch.jaxcache import use_compile_cache
 
 SUITES = {
     "table2": table2_frameworks.run,      # Megatron vs DeepSpeed
@@ -36,6 +37,7 @@ def main() -> None:
                     help="max speculation depth K for bench_decode's "
                          "speculative scenarios")
     args = ap.parse_args()
+    use_compile_cache()
     suite_kw = {"bench_decode": {"spec_depth": args.spec_depth}}
     print("name,us_per_call,derived")
     failures = 0

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import bubble_fraction, pipeline_forward, \
     split_stages
 
@@ -22,7 +23,7 @@ def main():
         print("need >=2 devices; set "
               "XLA_FLAGS=--xla_force_host_platform_device_count=4")
         return
-    mesh = jax.make_mesh((stages,), ("pipe",))
+    mesh = make_mesh((stages,), ("pipe",))
     d, layers, n_micro, mb = 64, 8, 8, 4
     w = jax.random.normal(jax.random.PRNGKey(0), (layers, d, d),
                           jnp.float32) * 0.2

@@ -16,9 +16,10 @@ Two kernel families live here:
   * :func:`paged_flash_prefix_partial` — the **multi-query** generalization:
     T query rows per sequence against the same paged prefix. The serving
     engine runs fused decode (T=1), chunked prefill and speculative verify
-    through this one family; the Pallas kernel packs all T rows of a kv
-    group into one row tile so a page is fetched into VMEM exactly once
-    per (sequence, kv head) and dotted against every query row.
+    through this one family; the Pallas kernel moves one whole page (all
+    kv heads) per grid step and packs all T rows of a kv group into one
+    row tile, so a page is fetched into VMEM exactly once per sequence
+    and dotted against every query row.
 
 The paged variants also ship an XLA fallback (`impl="xla"`) with identical
 partial semantics — a column loop over the block table that gathers one
@@ -157,14 +158,14 @@ def merge_partials(parts):
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  bs, scale, n_tblk, quant):
+                  bs, scale, n_tblk, n_kv, quant):
     if quant:
         (ks_ref, vs_ref, o_ref, m_ref, l_ref,
          acc_ref, mm_ref, ll_ref) = rest
     else:
         o_ref, m_ref, l_ref, acc_ref, mm_ref, ll_ref = rest
     ib = pl.program_id(0)
-    jb = pl.program_id(2)
+    jb = pl.program_id(1)
 
     @pl.when(jb == 0)
     def _init():
@@ -176,42 +177,47 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(jb * bs < length)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32) * scale         # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quant:  # int8 pages: dequantize block-wise in VMEM
-            k = k * ks_ref[0, :, 0, :]
-            v = v * vs_ref[0, :, 0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bs)
-        kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
-        m_prev = mm_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        ll_ref[...] = ll_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        mm_ref[...] = m_new
+        # one page tile holds every kv head; walk them with static slices
+        for hk in range(n_kv):
+            q = q_ref[0, hk].astype(jnp.float32) * scale     # (rows, D)
+            k = k_ref[0, :, hk, :].astype(jnp.float32)       # (bs, D)
+            v = v_ref[0, :, hk, :].astype(jnp.float32)
+            if quant:  # int8 pages: dequantize block-wise in VMEM
+                k = k * ks_ref[0, :, hk, :]
+                v = v * vs_ref[0, :, hk, :]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos < length, s, NEG_INF)
+            m_prev = mm_ref[hk]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            ll_ref[hk] = ll_ref[hk] * corr + jnp.sum(p, -1, keepdims=True)
+            acc_ref[hk] = acc_ref[hk] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            mm_ref[hk] = m_new
 
     @pl.when(jb == n_tblk - 1)
     def _finish():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)   # unnormalized
-        m_ref[0, 0] = mm_ref[...]
-        l_ref[0, 0] = ll_ref[...]
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)      # unnormalized
+        m_ref[0] = mm_ref[...]
+        l_ref[0] = ll_ref[...]
 
 
 def _paged_mq_pallas(q, k_pages, v_pages, table, lengths, k_scale,
                      v_scale, *, sm_scale, interpret):
     """Pallas multi-query paged partials: q (B, T, H, D) against the paged
-    prefix. All T rows (times their G heads per kv group) are packed into
-    ONE row tile, so the grid stays (B, K, table columns) and each page
-    tile is fetched into VMEM exactly once per (sequence, kv head) and
-    shared by the whole query window — the kernel body itself
-    (:func:`_paged_kernel`) is row-count-agnostic and is reused unchanged.
-    T=1 degenerates bitwise to the original single-query layout."""
+    prefix. The grid is (B, table columns) and each step moves ONE whole
+    page, all kv heads at once: block (1, bs, K, hd) of the
+    (n_blocks, bs, K, hd) pool. Its last two dims equal the pool's, which
+    is what Mosaic's tiling rule asks of a block (a per-head block
+    (1, bs, 1, hd) puts 1 on the sublane axis and is refused), and the int8
+    scale block (1, bs, K, 1) passes the same way. All T rows (times their
+    G heads per kv group) of a head are packed into one row tile, so a
+    page is fetched once per sequence and dotted against the whole query
+    window."""
     b, tq, h, d = q.shape
     nb, bs, n_kv, _ = k_pages.shape
     g = h // n_kv
@@ -221,43 +227,41 @@ def _paged_mq_pallas(q, k_pages, v_pages, table, lengths, k_scale,
     # group query rows by kv head: (B, K, T*G, D), T-major within a group
     qg = q.reshape(b, tq, n_kv, g, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, n_kv, rows, d)
-    kernel = functools.partial(_paged_kernel, bs=bs, n_tblk=mb, quant=quant,
+    kernel = functools.partial(_paged_kernel, bs=bs, n_tblk=mb, n_kv=n_kv,
+                               quant=quant,
                                scale=(sm_scale or 1.0 / np.sqrt(d)))
 
     # scalar-prefetch index maps: page blocks are addressed *through the
     # block table*, so only the live pages of each sequence ever move.
-    def page_idx(b_, k_, j, tbl, lens):
-        return (tbl[b_, j], 0, k_, 0)
+    def page_idx(b_, j, tbl, lens):
+        return (tbl[b_, j], 0, 0, 0)
 
-    def q_idx(b_, k_, j, tbl, lens):
-        return (b_, k_, 0, 0)
-
-    def out_idx(b_, k_, j, tbl, lens):
-        return (b_, k_, 0, 0)
+    def row_idx(b_, j, tbl, lens):
+        return (b_, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rows, d), q_idx),
-        pl.BlockSpec((1, bs, 1, d), page_idx),
-        pl.BlockSpec((1, bs, 1, d), page_idx),
+        pl.BlockSpec((1, n_kv, rows, d), row_idx),
+        pl.BlockSpec((1, bs, n_kv, d), page_idx),
+        pl.BlockSpec((1, bs, n_kv, d), page_idx),
     ]
     inputs = [qg, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1, 1), page_idx),
-                     pl.BlockSpec((1, bs, 1, 1), page_idx)]
+        in_specs += [pl.BlockSpec((1, bs, n_kv, 1), page_idx),
+                     pl.BlockSpec((1, bs, n_kv, 1), page_idx)]
         inputs += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv, mb),
+        grid=(b, mb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, rows, d), out_idx),
-            pl.BlockSpec((1, 1, rows, 1), out_idx),
-            pl.BlockSpec((1, 1, rows, 1), out_idx),
+            pl.BlockSpec((1, n_kv, rows, d), row_idx),
+            pl.BlockSpec((1, n_kv, rows, 1), row_idx),
+            pl.BlockSpec((1, n_kv, rows, 1), row_idx),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, rows, d), jnp.float32),
+            pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, rows, 1), jnp.float32),
         ],
     )
     o, m, l = pl.pallas_call(
@@ -352,9 +356,8 @@ def paged_flash_decode_partial(q, k_pages, v_pages, table, lengths, *,
     :func:`merge_partials`). Returns unnormalized (o f32, m, l).
 
     impl: "pallas" (block-indexed BlockSpec kernel), "xla" (bounded column
-    loop fallback), or "auto" — pallas on TPU, xla elsewhere. The pallas
-    path wants 128-aligned head_dim on real hardware; interpret mode takes
-    any shape. ``bound_scan=False`` (xla only) forces the unbounded
+    loop fallback), or "auto" — pallas on TPU, xla elsewhere.
+    ``bound_scan=False`` (xla only) forces the unbounded
     full-table scan — the regression oracle for the bounded contract.
     """
     if impl == "auto":

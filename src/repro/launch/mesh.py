@@ -12,6 +12,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def ensure_host_devices(n: int) -> None:
@@ -42,11 +43,17 @@ def ensure_host_devices(n: int) -> None:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Every mesh in the repo comes from here, with ``Auto`` axes: the
+    sharding code states layouts through ``with_sharding_constraint`` and
+    leaves the rest to GSPMD. ``jax.make_mesh`` defaults to ``Explicit``
+    axes (JAX >= 0.8), under which those constraints are refused."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1, data: Optional[int] = None):
@@ -56,7 +63,7 @@ def make_local_mesh(model: int = 1, data: Optional[int] = None):
     n = len(jax.devices())
     data = data or (n // model)
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_layout(mesh) -> dict:

@@ -17,25 +17,32 @@ LSE-merge shard-locally, with the model-axis psum/all-gather surfacing
 only at the row-parallel seams (wo, MLP down-proj, logits). The
 scheduler and block accounting stay host-global — policy is
 device-count-agnostic — and each engine step is still ONE dispatch.
-Greedy output is token-identical to --model-parallel 1 (sharded dense
-contractions accumulate in f32, see models/layers.dense).
+On CPU, greedy output is token-identical to --model-parallel 1
+(sharded dense contractions accumulate in f32, see models/layers.dense).
+On a TPU, tokens whose logits are nearly tied can differ between the two
+(the single-device engine reads attention through the Pallas kernel, the
+sharded one through the XLA loop).
+
+The arch runs at its published widths unless ``--reduced`` picks its CPU
+smoke config, as every example below does.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-        --requests 16 --int8-kv          # fused jit decode (default)
-    PYTHONPATH=src python -m repro.launch.serve --legacy   # per-layer loop
-    PYTHONPATH=src python -m repro.launch.serve \
+        --reduced --requests 16 --int8-kv    # fused jit decode (default)
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
+        --legacy                             # per-layer loop
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --prefill-chunk 16                   # paged chunked prefill
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --speculate ngram --spec-depth 8     # prompt-lookup speculation
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --speculate draft:qwen1.5-0.5b       # draft-model speculation
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --model-parallel 4                   # model-axis-sharded serving
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --deadline-s 2.0 --queue-cap 8       # SLO deadlines + load shedding
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --chaos 7                            # seeded fault injection
-    PYTHONPATH=src python -m repro.launch.serve \
+    PYTHONPATH=src python -m repro.launch.serve --reduced \
         --prefix-cache --prefill-chunk 16    # cross-request prefix caching
 
 ``--prefix-cache`` turns on cross-request prefix caching
@@ -91,11 +98,15 @@ def parse_mixed_lens(text: Optional[str]) -> Optional[List[int]]:
     return lens
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the CLI; errors exit through argparse."""
     ap = argparse.ArgumentParser()
-    # import inside main: --model-parallel may need to force host devices
-    # before anything initializes the jax backend
     ap.add_argument("--arch", default="qwen1.5-0.5b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="the arch's CPU smoke config (d_model 64, vocab "
+                         "256) instead of its published widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and of the prompts")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=8)
@@ -154,46 +165,24 @@ def main():
                     help="shard the engine over a model-axis mesh of N "
                          "devices (params via ShardCtx specs, paged KV/SSM "
                          "pools on their head axes); forces N host devices "
-                         "on CPU. Greedy output is token-identical to N=1")
+                         "on CPU. On CPU, greedy output is token-identical "
+                         "to N=1")
     grp = ap.add_mutually_exclusive_group()
     grp.add_argument("--fused", dest="mode", action="store_const",
                      const="fused", help="jit-compiled decode step (default)")
     grp.add_argument("--legacy", dest="mode", action="store_const",
                      const="legacy", help="per-layer Python decode loop")
     ap.set_defaults(mode="fused")
-    args = ap.parse_args()
-
-    if args.model_parallel > 1:
-        from repro.launch.mesh import ensure_host_devices
-        ensure_host_devices(args.model_parallel)
-
-    import jax
-
-    from repro.configs import get_config, list_archs
-    from repro.data.pipeline import serving_requests
-    from repro.launch.mesh import make_local_mesh
-    from repro.models.lm import LM
-    from repro.serving.engine import Engine, Rejected, Request
-    from repro.serving.faults import FaultInjector
-    from repro.serving.telemetry import Telemetry
-
+    args = ap.parse_args(argv)
+    from repro.configs import list_archs
     if args.arch not in list_archs():
         ap.error(f"unknown --arch {args.arch!r} (choose from "
                  f"{', '.join(list_archs())})")
     try:
-        lens = parse_mixed_lens(args.mixed_lens)
+        args.lens = parse_mixed_lens(args.mixed_lens)
     except ValueError as e:
         ap.error(str(e))
-    mesh = (make_local_mesh(model=args.model_parallel, data=1)
-            if args.model_parallel > 1 else None)
-
-    cfg = get_config(args.arch, reduced=True)
-    model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    faults = (FaultInjector.from_seed(args.chaos,
-                                      rids=range(args.requests))
-              if args.chaos is not None else None)
-    if faults is not None and args.mode != "fused":
+    if args.chaos is not None and args.mode != "fused":
         ap.error("--chaos requires the fused engine (drop --legacy)")
     if args.prefix_cache and not args.prefill_chunk:
         ap.error("--prefix-cache requires --prefill-chunk N: a cache hit "
@@ -202,36 +191,91 @@ def main():
                  "token-identical to a cache-off run")
     if args.trace_fenced and not args.trace_out:
         ap.error("--trace-fenced requires --trace-out PATH")
+    return args
+
+
+def build_engine(args: argparse.Namespace):
+    """Config, seeded random weights and the engine the flags describe.
+    Nothing is compiled yet: see :func:`warm_up`."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.launch.mesh import make_local_mesh
+    from repro.models.lm import LM
+    from repro.serving.engine import Engine
+    from repro.serving.faults import FaultInjector
+    from repro.serving.telemetry import Telemetry
+
+    mesh = (make_local_mesh(model=args.model_parallel, data=1)
+            if args.model_parallel > 1 else None)
+    cfg = get_config(args.arch, reduced=args.reduced)
+    params = LM(cfg).init(jax.random.PRNGKey(args.seed))
+    faults = (FaultInjector.from_seed(args.chaos,
+                                      rids=range(args.requests))
+              if args.chaos is not None else None)
     telemetry = Telemetry(enabled=bool(args.trace_out),
                           fenced=args.trace_fenced)
-    eng = Engine(cfg, params, max_batch=args.max_batch,
-                 n_blocks=args.n_blocks, block_size=args.block_size,
-                 kv_quant="int8" if args.int8_kv else "none",
-                 mode=args.mode,
-                 prefill_chunk=args.prefill_chunk or None,
-                 speculate=args.speculate, spec_depth=args.spec_depth,
-                 mesh=mesh, queue_cap=args.queue_cap or None,
-                 default_deadline_s=args.deadline_s or None,
-                 faults=faults, prefix_cache=args.prefix_cache,
-                 telemetry=telemetry)
+    return Engine(cfg, params, max_batch=args.max_batch,
+                  n_blocks=args.n_blocks, block_size=args.block_size,
+                  kv_quant="int8" if args.int8_kv else "none",
+                  mode=args.mode,
+                  prefill_chunk=args.prefill_chunk or None,
+                  speculate=args.speculate, spec_depth=args.spec_depth,
+                  mesh=mesh, queue_cap=args.queue_cap or None,
+                  default_deadline_s=args.deadline_s or None,
+                  faults=faults, prefix_cache=args.prefix_cache,
+                  telemetry=telemetry)
+
+
+def burst_prompts(args: argparse.Namespace, vocab: int) -> List[List[int]]:
+    """The synthetic burst the flags describe, drawn from ``--seed``."""
+    from repro.data.pipeline import serving_requests
+    return serving_requests(args.requests, vocab,
+                            prompt_len=args.prompt_len,
+                            prompt_lens=args.lens, seed=args.seed)
+
+
+def warm_up(eng, prompts: List[List[int]], max_new: int) -> None:
+    """Compile every executable the burst will need before it arrives."""
+    lens = sorted({len(p) for p in prompts})
     # warm every chunk-step table bucket the trace implies, not just the
     # widest: each distinct prompt length compiles its own footprint bucket
     # (a uniform trace still needs its prompt bucket, which can differ from
     # the max-footprint bucket warmup's max_seq_len argument implies)
-    eng.warmup(max(lens or [args.prompt_len]) + args.max_new,
-               prompt_lens=lens or [args.prompt_len])
-    for i, p in enumerate(serving_requests(args.requests, cfg.vocab_size,
-                                           prompt_len=args.prompt_len,
-                                           prompt_lens=lens)):
+    eng.warmup(max(lens) + max_new, prompt_lens=lens)
+
+
+def serve_burst(eng, prompts: List[List[int]], max_new: int):
+    """Submit the burst and run the engine until it drains. Returns the
+    finished requests."""
+    from repro.serving.engine import Rejected, Request
+
+    for i, p in enumerate(prompts):
         try:
-            eng.submit(Request(rid=i, tokens=p,
-                               max_new_tokens=args.max_new))
+            eng.submit(Request(rid=i, tokens=list(p),
+                               max_new_tokens=max_new))
         except Rejected as e:
             # load shedding is a reported outcome, not a launcher crash
             print(f"{'rejected':>20s}: rid={i} ({e.reason})")
-    eng.run()
-    if faults is not None:
-        faults.release_all(eng)     # return any still-squeezed blocks
+    return eng.run()
+
+
+def main(argv=None):
+    """Serve one burst and print the stats. Returns the drained engine."""
+    args = parse_args(argv)
+    # before anything initializes the jax backend
+    if args.model_parallel > 1:
+        from repro.launch.mesh import ensure_host_devices
+        ensure_host_devices(args.model_parallel)
+    from repro.launch.jaxcache import use_compile_cache
+    use_compile_cache()
+
+    eng = build_engine(args)
+    prompts = burst_prompts(args, eng.cfg.vocab_size)
+    warm_up(eng, prompts, args.max_new)
+    serve_burst(eng, prompts, args.max_new)
+    if eng.faults is not None:
+        eng.faults.release_all(eng)     # return any still-squeezed blocks
         # the injector mirrors every applied action into the telemetry
         # event log (faults._note), so the replay record printed here is
         # the same stream a --trace-out viewer sees on the chaos track
@@ -251,6 +295,7 @@ def main():
                       "model_parallel": args.model_parallel})
         print(f"{'trace_out':>20s}: {args.trace_out} "
               f"({len(trace['traceEvents'])} events)")
+    return eng
 
 
 if __name__ == "__main__":

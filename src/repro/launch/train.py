@@ -14,6 +14,7 @@ import jax
 from repro.configs import get_config, list_archs
 from repro.core.config import SHAPES, ShapeSpec, technique_from_label
 from repro.core.trainer import Trainer, TrainerConfig
+from repro.launch.jaxcache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--mesh-model", type=int, default=1,
                     help="model-axis size for a local mesh (1 = no mesh)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     shape = (SHAPES[args.shape] if args.shape

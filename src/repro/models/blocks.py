@@ -249,7 +249,6 @@ def _moe_local(h, p, cfg: ArchConfig, capacity_mult: float) -> Tuple[jax.Array, 
 def _moe_ep(h, p, cfg: ArchConfig, ctx, capacity_mult: float):
     """Expert-parallel dispatch: shard_map over the mesh; tokens exchanged
     with all-to-all along the model axis (experts sharded over `model`)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d, e, k = cfg.d_model, cfg.n_experts, cfg.top_k
@@ -309,10 +308,10 @@ def _moe_ep(h, p, cfg: ArchConfig, ctx, capacity_mult: float):
 
     wq_specs = (P(None, None), P(maxis, None, None), P(maxis, None, None),
                 P(maxis, None, None))
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(h_spec,) + wq_specs,
-                   out_specs=(h_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(h_spec,) + wq_specs,
+                       out_specs=(h_spec, P()),
+                       check_vma=False)
     wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
     return fn(h, p["router"], wg, wu, wd)
 

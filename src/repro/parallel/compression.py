@@ -18,7 +18,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 BLOCK = 1024
@@ -73,8 +72,8 @@ def compressed_psum(mesh, dp_axes, g_local, err):
             acc = jax.lax.psum(acc, ax)
         return acc
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=P(), check_vma=False)
     reduced = fn(q, s)
     import numpy as np
     flat = reduced.reshape(-1)[: int(np.prod(g_local.shape))]

@@ -19,7 +19,6 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -77,10 +76,10 @@ def pipeline_forward(mesh: Mesh, axis: str, stage_fn: Callable,
         return outputs
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis), {"_": 0})["_"]
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis), P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis), P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn
 
 

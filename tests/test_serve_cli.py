@@ -24,3 +24,20 @@ def test_parse_mixed_lens_happy_path():
 def test_parse_mixed_lens_rejects_malformed(bad, msg):
     with pytest.raises(ValueError, match=msg):
         parse_mixed_lens(bad)
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py has no CPU fallback: without a TPU it exits non-zero
+    and prints no result line."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=repo,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs a TPU" in proc.stderr

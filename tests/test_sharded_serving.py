@@ -179,3 +179,61 @@ def test_tp_indivisible_heads_degrade_to_replication():
     assert spec[3] == expected
     # a dimension the degree does not divide replicates instead of raising
     assert ctx.spec_for("kv_pool", (2, 8, 8, 3, 16))[3] is None
+
+
+def test_serve_cli_model_parallel_matches_tp1_in_subprocess():
+    """``serve --reduced --model-parallel 4`` on four forced CPU devices
+    emits the TP=1 tokens. Runs in a child so the parent needs no forced
+    devices (the tier-1 command forces none, which skips the tests above)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import json\n"
+        "from repro.launch import serve\n"
+        "out = {}\n"
+        "for tp in (1, 4):\n"
+        "    eng = serve.main(['--reduced', '--model-parallel', str(tp)])\n"
+        "    assert eng.tp_degree == tp\n"
+        "    out[tp] = {r.rid: r.output for r in eng.finished}\n"
+        "print(json.dumps(out))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-4000:]
+    out = json.loads(proc.stdout.splitlines()[-1])
+    assert len(out["1"]) == 8
+    assert out["4"] == out["1"]
+
+
+def _bench_common():
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks import common
+    return common
+
+
+def test_bench_tp_rows_run_in_process_off_cpu(monkeypatch):
+    """On a chip host the TP rows run in this process, which holds the
+    chips; degrees wider than the device count are left out."""
+    common = _bench_common()
+    monkeypatch.setattr(common.jax, "default_backend", lambda: "tpu")
+    rows = list(common.run_model_parallel_rows(
+        "unused", lambda tp: {"tp": tp}, (1, 2, 4), 8))
+    assert rows == [(tp, {"tp": tp}) for tp in (1, 2, 4)
+                    if tp <= jax.device_count()]
+
+
+def test_bench_tp_rows_failed_child_raises():
+    """A TP degree whose child process fails stops the sweep."""
+    common = _bench_common()
+    with pytest.raises(RuntimeError, match="tp2 child failed"):
+        list(common.run_model_parallel_rows(
+            "benchmarks.no_such_module", None, (2,), 2))

@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.config import Technique
+from repro.launch.mesh import make_mesh
 
 
 # --------------------------------------------------------------------------
@@ -145,7 +146,7 @@ def test_pipeline_forward_matches_sequential():
     if n_dev < 2:
         pytest.skip("needs >=2 devices (run under dryrun env for more)")
     stages = 2
-    mesh = jax.make_mesh((stages,), ("pipe",))
+    mesh = make_mesh((stages,), ("pipe",))
     from repro.parallel.pipeline import pipeline_forward, split_stages
     d = 16
     w = jax.random.normal(jax.random.PRNGKey(0), (4, d, d), jnp.float32) * 0.3
@@ -243,7 +244,7 @@ def test_sharding_resolver_zero_stages():
     import jax as _jax
     from repro.parallel.sharding import make_shard_ctx, resolve_spec
     cfg = get_config("granite-3-2b")
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")
