@@ -1,0 +1,248 @@
+"""From a configuration file to a warmed serving engine.
+
+The configuration file (``bench/configs/<name>.json``) holds the model's
+published ``config.json`` keys as they are run, and an ``engine`` section
+(block size, prefill chunk, ``max_batch``, KV dtype, chips); the KV pool
+is always fitted to the device (:func:`fit_pool`). The weights are made
+here, from the seed, not by the program: :func:`make_weights` draws every
+leaf on the device in one jitted call, in bf16, and checks that the tree
+has the layout the program's model expects.
+"""
+from __future__ import annotations
+
+import math
+from typing import Callable, Dict, List, Tuple
+
+import numpy as np
+
+from bench.flops import Shape
+
+# keys of a source config that the serving stack does not model: the run
+# may only hold them at the value that changes nothing
+NEUTRAL = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
+           "logits_scaling": 1.0}
+VOCAB_PAD = 512
+POOL_SHARE = 0.8    # of the device's memory, for the pool and the steps' plans
+
+
+def head_dim(conf) -> int:
+    return int(conf.get("head_dim")
+               or conf["hidden_size"] // conf["num_attention_heads"])
+
+
+def arch_config(conf):
+    """The program's ``ArchConfig`` for a dense decoder configuration."""
+    from repro.core.config import ArchConfig
+
+    for key, neutral in NEUTRAL.items():
+        if float(conf.get(key, neutral)) != neutral:
+            raise ValueError(f"{conf['name']}: {key}={conf[key]} is not "
+                             f"modelled by the serving stack")
+    hd = head_dim(conf)
+    if "attention_multiplier" in conf and not math.isclose(
+            conf["attention_multiplier"], hd ** -0.5):
+        raise ValueError(f"{conf['name']}: attention_multiplier "
+                         f"{conf['attention_multiplier']} is not 1/sqrt(hd)")
+    return ArchConfig(
+        name=conf["name"], family="dense",
+        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"], head_dim=hd,
+        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
+        qkv_bias=qkv_bias(conf), rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        tie_embeddings=bool(conf["tie_word_embeddings"]))
+
+
+def qkv_bias(conf) -> bool:
+    return bool(conf.get("attention_bias", conf["model_type"] == "qwen2"))
+
+
+def shape(conf, es) -> Shape:
+    return Shape(layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+                 heads=conf["num_attention_heads"],
+                 kv_heads=conf["num_key_value_heads"], head_dim=head_dim(conf),
+                 d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
+                 block=es["block_size"],
+                 kv_bytes=1 if es["kv_dtype"] == "int8" else 2)
+
+
+def padded_vocab(v: int) -> int:
+    return -(-v // VOCAB_PAD) * VOCAB_PAD
+
+
+def layout(conf) -> Dict:
+    """Every weight as (shape, init): ``ones``, ``embed``, ``bias``, or an
+    int, the fan-in a normal draw is scaled by."""
+    L, d = conf["num_hidden_layers"], conf["hidden_size"]
+    h, k, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
+                head_dim(conf))
+    f = conf["intermediate_size"]
+    mix = {"ln": ((L, d), "ones"),
+           "wq": ((L, d, h, hd), d), "wk": ((L, d, k, hd), d),
+           "wv": ((L, d, k, hd), d), "wo": ((L, h, hd, d), h * hd)}
+    if qkv_bias(conf):
+        mix.update(bq=((L, h, hd), "bias"), bk=((L, k, hd), "bias"),
+                   bv=((L, k, hd), "bias"))
+    ffn = {"ln": ((L, d), "ones"), "w_gate": ((L, d, f), d),
+           "w_up": ((L, d, f), d), "w_down": ((L, f, d), f)}
+    return {"embed": ((padded_vocab(conf["vocab_size"]), d), "embed"),
+            "final_ln": ((d,), "ones"),
+            "blocks": {"pos0": {"mix": mix, "ffn": ffn}}}
+
+
+def _leaves(tree, prefix=()):
+    for key in sorted(tree):
+        val = tree[key]
+        if isinstance(val, dict):
+            yield from _leaves(val, prefix + (key,))
+        else:
+            yield prefix + (key,), val
+
+
+def seed_key(seed: int):
+    """A PRNG key from any whole number (the driver's seeds pass 2**31)."""
+    import jax
+    word = np.random.SeedSequence(int(seed)).generate_state(1)[0]
+    return jax.random.PRNGKey(int(word))
+
+
+def make_weights(conf, seed: int):
+    """All weights, bf16, on the default device, from one jitted call."""
+    import jax
+    import jax.numpy as jnp
+
+    lay = layout(conf)
+    vocab = conf["vocab_size"]
+
+    def draw(key):
+        out: Dict = {}
+        for i, (path, (shp, init)) in enumerate(_leaves(lay)):
+            k = jax.random.fold_in(key, i)
+            if init == "ones":
+                a = jnp.ones(shp, jnp.float32)
+            elif init == "bias":
+                a = 0.1 * jax.random.normal(k, shp, jnp.float32)
+            elif init == "embed":
+                a = jax.random.normal(k, shp, jnp.float32) / math.sqrt(shp[1])
+                a = jnp.where(jnp.arange(shp[0])[:, None] < vocab, a, 0.0)
+            else:
+                a = jax.random.normal(k, shp, jnp.float32) / math.sqrt(init)
+            node = out
+            for p in path[:-1]:
+                node = node.setdefault(p, {})
+            node[path[-1]] = a.astype(jnp.bfloat16)
+        return out
+
+    return jax.jit(draw)(seed_key(seed))
+
+
+def check_layout(cfg, params) -> None:
+    """The weights must have exactly the tree the program's model reads."""
+    import jax
+    from repro.models.lm import LM
+
+    want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype),
+                                  LM(cfg).abstract_params())
+    got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
+    if want != got:
+        raise ValueError(f"weight layout differs from the model's: "
+                         f"{want} != {got}")
+
+
+def build_engine(cfg, params, es, n_blocks: int):
+    from repro.serving.engine import Engine
+    return Engine(cfg, params, max_batch=es["max_batch"], n_blocks=n_blocks,
+                  block_size=es["block_size"],
+                  kv_quant="int8" if es["kv_dtype"] == "int8" else "none",
+                  prefill_chunk=es["prefill_chunk"])
+
+
+def buckets(max_tokens: int, block: int) -> List[int]:
+    """Power-of-two block-table widths up to the one ``max_tokens`` needs:
+    every width a decode or chunk step of the cell can be given."""
+    top = 1 << (-(-max_tokens // block) - 1).bit_length()
+    return [1 << i for i in range(top.bit_length())]
+
+
+def _lower(eng, kind: str, n_blocks: int, width: int, sharding):
+    import jax
+    import jax.numpy as jnp
+
+    def sds(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), eng.params)
+    kv = {k: sds((a.shape[0], n_blocks) + tuple(a.shape[2:]), a.dtype)
+          for k, a in eng.kv.state.items()}
+    i32, per = jnp.int32, eng.model.n_periods
+    if kind == "decode":
+        b = eng.max_batch
+        return eng._fused_step.lower(
+            params, kv, {}, sds((b,), i32), sds((b,), i32),
+            sds((b, width), i32), sds((b,), jnp.bool_),
+            sds((per, b), jnp.bool_)).compile()
+    cn = eng.prefill_chunk
+    return eng._chunk_step.lower(
+        params, kv, {}, sds((1, cn), i32), sds((), i32), sds((), i32),
+        sds((1, width), i32), sds((), i32), sds((per, 1), jnp.bool_)).compile()
+
+
+def plan_bytes(compiled) -> int:
+    """Device bytes a compiled step plans for: arguments, outputs and
+    temporaries, less the donated buffers its outputs reuse."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def fit_pool(cfg, params, es, width: int, device, log: Callable) -> Tuple[int, dict]:
+    """The most blocks whose decode and chunk steps at the widest table,
+    beside the live pool, fit :data:`POOL_SHARE` of the device's memory.
+
+    The steps' plans grow linearly with the pool, so two small probes give
+    the line; the pool found is compiled once more to confirm it. Every
+    program here is the same in every run, so the persistent cache holds
+    them after the first."""
+    from jax.sharding import SingleDeviceSharding
+
+    limit = device.memory_stats()["bytes_limit"]
+    budget = POOL_SHARE * limit
+    probe = build_engine(cfg, params, es, 2 * es["max_batch"])
+    per_block = probe.kv.hbm_bytes() // probe.kv_cfg.n_blocks
+    shard = SingleDeviceSharding(device)
+
+    def need(n):
+        plans = {k: plan_bytes(_lower(probe, k, n, width, shard))
+                 for k in ("decode", "chunk")}
+        return max(plans.values()) + per_block * n, plans
+
+    try:
+        lo, hi = 256, 512
+        (a, _), (b, _) = need(lo), need(hi)
+        slope = (b - a) / (hi - lo)
+        n = int((budget - (a - slope * lo)) // slope) // 64 * 64
+        while n > 0:
+            total, plans = need(n)
+            if total <= budget:
+                break
+            n -= 64
+    finally:
+        for x in probe.kv.state.values():
+            x.delete()
+    if n <= 0:
+        raise RuntimeError(f"no pool fits {device}")
+    info = {"blocks": n, "pool_bytes": per_block * n,
+            "decode_plan_bytes": plans["decode"],
+            "chunk_plan_bytes": plans["chunk"], "budget_bytes": int(budget),
+            "device_limit_bytes": limit}
+    log(f"pool: {info}")
+    return n, info
+
+
+def warm(eng, widths: List[int]) -> None:
+    """Compile the decode and chunk steps at every table width the cell can
+    use (``Engine.warmup`` compiles both at the width ``max_seq_len``
+    implies)."""
+    for w in widths:
+        eng.warmup(w * eng.block_size)

@@ -1,0 +1,67 @@
+"""bench/trace_reduce.py on a small trace in the profiler's own format:
+two TPU ops that overlap, the paged kernel, and host spans."""
+import pytest
+
+from bench import trace_reduce
+
+# times in ns from 1000: device busy [1000, 4000) and [6000, 7000),
+# idle [4000, 6000) under bench.record and [7000, 9000) under bench.wait
+TRACE = """
+planes { id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 2000000 }
+    events { metadata_id: 2 offset_ps: 1000000 duration_ps: 2000000 }
+    events { metadata_id: 2 offset_ps: 5000000 duration_ps: 1000000 } }
+  lines { id: 2 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 8000000 } }
+  event_metadata { key: 1 value { id: 1 name: "fusion.7" } }
+  event_metadata { key: 2 value { id: 2 name: "_paged_kernel" } } }
+planes { id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 3500000 }
+    events { metadata_id: 2 offset_ps: 3500000 duration_ps: 2000000 }
+    events { metadata_id: 3 offset_ps: 5500000 duration_ps: 1000000 }
+    events { metadata_id: 4 offset_ps: 6500000 duration_ps: 1500000 }
+    events { metadata_id: 5 offset_ps: 0 duration_ps: 500000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.step" } }
+  event_metadata { key: 2 value { id: 2 name: "bench.record" } }
+  event_metadata { key: 3 value { id: 3 name: "bench.step" } }
+  event_metadata { key: 4 value { id: 4 name: "bench.wait" } }
+  event_metadata { key: 5 value { id: 5 name: "PjitFunction" } } }
+"""
+
+
+@pytest.fixture
+def trace_file(tmp_path):
+    from jax.profiler import ProfileData
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    path = d / "host.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(TRACE))
+    return tmp_path
+
+
+def test_busy_union_kernel_time_and_named_gaps(trace_file):
+    r = trace_reduce.read(trace_reduce.find(str(trace_file)), "paged_kernel")
+    assert r["window_s"] == pytest.approx(8000e-9)      # 1000 .. 9000
+    assert r["busy_s"] == pytest.approx(4000e-9)        # overlap counted once
+    assert r["kernel_s"] == pytest.approx(3000e-9)
+    assert r["kernel_calls"] == 2
+    assert r["ops"]["fusion.7"] == pytest.approx(2000e-9)
+    assert r["gaps"] == [("bench.record", pytest.approx(2000e-9)),
+                         ("bench.wait", pytest.approx(2000e-9))] or \
+        r["gaps"] == [("bench.wait", pytest.approx(2000e-9)),
+                      ("bench.record", pytest.approx(2000e-9))]
+    assert r["idle_by_span"] == {"bench.record": pytest.approx(2000e-9),
+                                 "bench.wait": pytest.approx(2000e-9)}
+
+
+def test_union_and_gaps():
+    merged = trace_reduce.union([(5, 7), (0, 2), (1, 3), (6, 6.5)])
+    assert merged == [(0, 3), (5, 7)]
+    assert trace_reduce.gaps_between(merged, 0, 10) == [(3, 5), (7, 10)]
+
+
+def test_no_trace_file(tmp_path):
+    with pytest.raises(FileNotFoundError):
+        trace_reduce.find(str(tmp_path))
