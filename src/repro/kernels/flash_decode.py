@@ -131,6 +131,7 @@ def flash_decode_partial(q, k, v, lengths, *, bk: int = 256,
             pltpu.VMEM((g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(lengths, qg, k, v)
     return (o.reshape(b, h, d), m.reshape(b, h, 1), l.reshape(b, h, 1))
 
@@ -207,7 +208,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_mq_pallas(q, k_pages, v_pages, table, lengths, k_scale,
-                     v_scale, *, sm_scale, interpret):
+                     v_scale, *, sm_scale, interpret, name="paged_prefix"):
     """Pallas multi-query paged partials: q (B, T, H, D) against the paged
     prefix. The grid is (B, table columns) and each step moves ONE whole
     page, all kv heads at once: block (1, bs, K, hd) of the
@@ -217,7 +218,9 @@ def _paged_mq_pallas(q, k_pages, v_pages, table, lengths, k_scale,
     scale block (1, bs, K, 1) passes the same way. All T rows (times their
     G heads per kv group) of a head are packed into one row tile, so a
     page is fetched once per sequence and dotted against the whole query
-    window."""
+    window. ``name`` names the kernel on the device: ``paged_prefix`` for
+    a T-row window (chunked prefill, verify), ``paged_decode`` for fused
+    decode's one row."""
     b, tq, h, d = q.shape
     nb, bs, n_kv, _ = k_pages.shape
     g = h // n_kv
@@ -273,6 +276,7 @@ def _paged_mq_pallas(q, k_pages, v_pages, table, lengths, k_scale,
             jax.ShapeDtypeStruct((b, n_kv, rows, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(table, lengths, *inputs)
 
     def unpack(a):
@@ -287,7 +291,7 @@ def _paged_partial_pallas(q, k_pages, v_pages, table, lengths, k_scale,
                           v_scale, *, sm_scale, interpret):
     o, m, l = _paged_mq_pallas(q[:, None], k_pages, v_pages, table, lengths,
                                k_scale, v_scale, sm_scale=sm_scale,
-                               interpret=interpret)
+                               interpret=interpret, name="paged_decode")
     return o[:, 0], m[:, 0], l[:, 0]
 
 

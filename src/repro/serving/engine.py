@@ -643,56 +643,66 @@ class Engine:
         by_len: Dict[int, List[Request]] = {}
         for r in reqs:
             by_len.setdefault(r.context_len(), []).append(r)
-        for t in sorted(by_len):
-            self._prefill_group(by_len[t], t)
+        with self.telemetry.phase("prefill", rows=len(reqs)):
+            for t in sorted(by_len):
+                self._prefill_group(by_len[t], t)
 
     def _prefill_fwd_impl(self, params, toks):
         logits, cache, _ = self.model.prefill(params, {"tokens": toks})
         return logits, cache
 
     def _prefill_group(self, group: List[Request], t: int) -> None:
-        self.telemetry.mark_kind("prefill")
-        toks = jnp.asarray([r.context_tokens() for r in group], jnp.int32)
-        logits, cache = self._prefill_fwd(self.params, toks)
-        if self._attn_pos:
-            ks, vs = [], []
-            for pos in self._attn_pos:
-                c = cache[f"pos{pos}"]
-                if isinstance(c, dict) and "self" in c:
-                    c = c["self"]
-                ks.append(c["k"])            # (n_periods, G, T, K, hd)
-                vs.append(c["v"])
-            lkv = (len(group), t, self.kv_cfg.n_kv_heads, self.kv_cfg.head_dim)
-            k_all = jnp.stack(ks, axis=1).reshape((-1,) + lkv)  # (L, G, T, ..)
-            v_all = jnp.stack(vs, axis=1).reshape((-1,) + lkv)
-        for g, r in enumerate(group):
+        tel = self.telemetry
+        tel.mark_kind("prefill")
+        with tel.phase("inputs"):
+            toks = jnp.asarray([r.context_tokens() for r in group], jnp.int32)
+        # the forward, then the page-out of its KV and SSM states
+        with tel.phase("launch"):
+            logits, cache = self._prefill_fwd(self.params, toks)
             if self._attn_pos:
-                self.kv.write_prefill((k_all[:, g], v_all[:, g]), r.blocks)
-            for pos in self._ssm_pos:
-                c = cache[f"pos{pos}"]
-                st = self._ssm_states[f"pos{pos}"]
-                self._ssm_states[f"pos{pos}"] = jax.tree_util.tree_map(
-                    # repro: allow[CACHE-01] r.slot is a host int the scheduler just assigned; no sentinel on the slot axis
-                    lambda full, new: full.at[:, r.slot].set(new[:, g]),
-                    st, c)
-        next_tok = np.asarray(jnp.argmax(logits, axis=-1))
-        row_ok = np.asarray(jnp.all(
-            jnp.isfinite(logits.astype(jnp.float32)), axis=-1))
-        now = self.clock()
-        for g, r in enumerate(group):
-            if not row_ok[g]:       # poisoned prompt forward: quarantine
-                self._evict_terminal(r, FAILED)
-                continue
-            if not r.output:        # fresh request: this IS the first token
-                r.output.append(int(next_tok[g]))
-                r.first_token_time = now
-                self.telemetry.req_first_token(r)
-            # resumed request: the recomputed token is already output[-1]
-            r.prefilled = t
-            r.state = RUNNING
-            self.telemetry.req_running(r)
-            self.prefill_tokens += t
-            self._cache_register(r)
+                ks, vs = [], []
+                for pos in self._attn_pos:
+                    c = cache[f"pos{pos}"]
+                    if isinstance(c, dict) and "self" in c:
+                        c = c["self"]
+                    ks.append(c["k"])            # (n_periods, G, T, K, hd)
+                    vs.append(c["v"])
+                lkv = (len(group), t, self.kv_cfg.n_kv_heads,
+                       self.kv_cfg.head_dim)
+                # (L, G, T, ..)
+                k_all = jnp.stack(ks, axis=1).reshape((-1,) + lkv)
+                v_all = jnp.stack(vs, axis=1).reshape((-1,) + lkv)
+            for g, r in enumerate(group):
+                if self._attn_pos:
+                    self.kv.write_prefill((k_all[:, g], v_all[:, g]),
+                                          r.blocks)
+                for pos in self._ssm_pos:
+                    c = cache[f"pos{pos}"]
+                    st = self._ssm_states[f"pos{pos}"]
+                    self._ssm_states[f"pos{pos}"] = jax.tree_util.tree_map(
+                        # repro: allow[CACHE-01] r.slot is a host int the scheduler just assigned; no sentinel on the slot axis
+                        lambda full, new: full.at[:, r.slot].set(new[:, g]),
+                        st, c)
+        with tel.phase("wait"):
+            next_tok = np.asarray(jnp.argmax(logits, axis=-1))
+            row_ok = np.asarray(jnp.all(
+                jnp.isfinite(logits.astype(jnp.float32)), axis=-1))
+        with tel.phase("finish"):
+            now = self.clock()
+            for g, r in enumerate(group):
+                if not row_ok[g]:       # poisoned prompt forward: quarantine
+                    self._evict_terminal(r, FAILED)
+                    continue
+                if not r.output:    # fresh request: this IS the first token
+                    r.output.append(int(next_tok[g]))
+                    r.first_token_time = now
+                    tel.req_first_token(r)
+                # resumed request: the recomputed token is already output[-1]
+                r.prefilled = t
+                r.state = RUNNING
+                tel.req_running(r)
+                self.prefill_tokens += t
+                self._cache_register(r)
 
     # ------------------------------------------------------------------
     # Shared layer body. The fused decode step, the chunked-prefill step
@@ -882,40 +892,48 @@ class Engine:
         tel = self.telemetry
         tel.mark_kind("chunk")
         tc0 = tel.clock() if tel.enabled else 0.0
-        self._cow_tail(req, pos=start)
-        seq = req.context_tokens()
-        cn = self.prefill_chunk
-        chunk = seq[start:start + n] + [0] * (cn - n)
-        # fixed table width per request footprint: every chunk of this
-        # request compiles against the same bucket
-        mbb = _next_pow2(self.sched._blocks_for(len(seq)))
-        table = np.zeros((1, mbb), np.int32)
-        table[0, : len(req.blocks)] = req.blocks
-        kv_state, ssm_states, next_tok, ok = self._chunk_step(
-            self.params, self.kv.state, self._ssm_states,
-            jnp.asarray([chunk], jnp.int32),
-            jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32),
-            jnp.asarray(table), jnp.asarray(req.slot, jnp.int32),
-            jnp.asarray(self._inj_mask(1, [(0, req)])))
-        self.kv.state = kv_state
-        if self._ssm_pos:
-            self._ssm_states = ssm_states
-        if not bool(ok):
-            # poisoned mid-prefill: quarantine before any state leaks into
-            # the request's lifecycle (its pages are scrubbed on eviction)
-            self._evict_terminal(req, FAILED)
-            return
-        req.prefilled = start + n
-        self.prefill_tokens += n
-        tel.req_chunk(req, tc0, start, n)
-        self._cache_register(req)
-        if req.prefilled >= len(seq):
-            if not req.output:      # fresh request: this IS the first token
-                req.output.append(int(next_tok))
-                req.first_token_time = self.clock()
-                tel.req_first_token(req)
-            req.state = RUNNING
-            tel.req_running(req)
+        with tel.phase("chunk", rid=req.rid, start=start, n=n):
+            with tel.phase("inputs"):
+                self._cow_tail(req, pos=start)
+                seq = req.context_tokens()
+                cn = self.prefill_chunk
+                chunk = seq[start:start + n] + [0] * (cn - n)
+                # fixed table width per request footprint: every chunk of
+                # this request compiles against the same bucket
+                mbb = _next_pow2(self.sched._blocks_for(len(seq)))
+                table = np.zeros((1, mbb), np.int32)
+                table[0, : len(req.blocks)] = req.blocks
+                args = (jnp.asarray([chunk], jnp.int32),
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(n, jnp.int32), jnp.asarray(table),
+                        jnp.asarray(req.slot, jnp.int32),
+                        jnp.asarray(self._inj_mask(1, [(0, req)])))
+            with tel.phase("launch"):
+                kv_state, ssm_states, next_tok, ok = self._chunk_step(
+                    self.params, self.kv.state, self._ssm_states, *args)
+            self.kv.state = kv_state
+            if self._ssm_pos:
+                self._ssm_states = ssm_states
+            with tel.phase("wait"):
+                ok = bool(ok)
+            with tel.phase("finish"):
+                if not ok:
+                    # poisoned mid-prefill: quarantine before any state
+                    # leaks into the request's lifecycle (its pages are
+                    # scrubbed on eviction)
+                    self._evict_terminal(req, FAILED)
+                    return
+                req.prefilled = start + n
+                self.prefill_tokens += n
+                tel.req_chunk(req, tc0, start, n)
+                self._cache_register(req)
+                if req.prefilled >= len(seq):
+                    if not req.output:  # fresh request: its first token
+                        req.output.append(int(next_tok))
+                        req.first_token_time = self.clock()
+                        tel.req_first_token(req)
+                    req.state = RUNNING
+                    tel.req_running(req)
 
     # ------------------------------------------------------------------
     # Fused decode: the whole step — embed, layer-stack scan with paged
@@ -1005,30 +1023,40 @@ class Engine:
     def _decode_fused(self, live: List[Request]) -> None:
         if not live:
             return
-        self.telemetry.mark_kind("decode")
-        bsz = self.max_batch
-        tokens = np.zeros((bsz,), np.int32)
-        lengths = np.zeros((bsz,), np.int32)
-        active = np.zeros((bsz,), bool)
-        for r in live:
-            self._cow_tail(r)
-        mbb = _next_pow2(max(len(r.blocks) for r in live))
-        table = np.zeros((bsz, mbb), np.int32)
-        for r in live:
-            tokens[r.slot] = r.output[-1]
-            lengths[r.slot] = r.length - 1          # current KV length
-            active[r.slot] = True
-            table[r.slot, : len(r.blocks)] = r.blocks
-        kv_state, ssm_states, next_tokens, _, row_ok = self._fused_step(
-            self.params, self.kv.state, self._ssm_states,
-            jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(table),
-            jnp.asarray(active),
-            jnp.asarray(self._inj_mask(bsz, ((r.slot, r) for r in live))))
-        self.kv.state = kv_state
-        if ssm_states:
-            self._ssm_states = ssm_states
-        self._finish_step(live, np.asarray(next_tokens),
-                          row_ok=np.asarray(row_ok))
+        tel = self.telemetry
+        tel.mark_kind("decode")
+        with tel.phase("decode", rows=len(live),
+                       kv_tokens=sum(r.length - 1 for r in live)):
+            with tel.phase("inputs"):
+                bsz = self.max_batch
+                tokens = np.zeros((bsz,), np.int32)
+                lengths = np.zeros((bsz,), np.int32)
+                active = np.zeros((bsz,), bool)
+                for r in live:
+                    self._cow_tail(r)
+                mbb = _next_pow2(max(len(r.blocks) for r in live))
+                table = np.zeros((bsz, mbb), np.int32)
+                for r in live:
+                    tokens[r.slot] = r.output[-1]
+                    lengths[r.slot] = r.length - 1      # current KV length
+                    active[r.slot] = True
+                    table[r.slot, : len(r.blocks)] = r.blocks
+                args = (jnp.asarray(tokens), jnp.asarray(lengths),
+                        jnp.asarray(table), jnp.asarray(active),
+                        jnp.asarray(self._inj_mask(
+                            bsz, ((r.slot, r) for r in live))))
+            with tel.phase("launch"):
+                kv_state, ssm_states, next_tokens, _, row_ok = \
+                    self._fused_step(self.params, self.kv.state,
+                                     self._ssm_states, *args)
+            self.kv.state = kv_state
+            if ssm_states:
+                self._ssm_states = ssm_states
+            with tel.phase("wait"):
+                next_tokens = np.asarray(next_tokens)
+                row_ok = np.asarray(row_ok)
+            with tel.phase("finish"):
+                self._finish_step(live, next_tokens, row_ok=row_ok)
 
     # ------------------------------------------------------------------
     # Speculative decoding: a proposer (serving/speculate.py) guesses up
@@ -1148,75 +1176,89 @@ class Engine:
         an elder) rides along at depth 0 — plain decode semantics."""
         if not live:
             return
-        bsz = self.max_batch
-        t = self.spec.depth + 1
-        tokens = np.zeros((bsz, t), np.int32)
-        ctx = np.zeros((bsz,), np.int32)
-        n_valid = np.zeros((bsz,), np.int32)
-        active = np.zeros((bsz,), bool)
-        n_props: Dict[int, int] = {}
-        rows: List[Request] = []
-        for r in sorted(live, key=lambda r: (r.arrival, r.rid)):
-            if r.state != RUNNING:      # preempted by an elder's growth
-                continue
-            budget = r.max_new_tokens - len(r.output) - 1
-            k = self.spec.depth_for(r, budget) if budget >= 1 else 0
-            props = self.spec.propose(r, k) if k >= 1 else []
-            # the verify window appends up to len(props)+1 tokens of KV;
-            # iteration is oldest-first, so growth can only preempt rows
-            # not yet gathered (strictly younger requests)
-            if props and not self.sched.ensure_blocks(
-                    r, r.length + len(props)):
-                props = []
-            tokens[r.slot, 0] = r.output[-1]
-            tokens[r.slot, 1: 1 + len(props)] = props
-            ctx[r.slot] = r.length - 1          # current KV length
-            n_valid[r.slot] = 1 + len(props)
-            active[r.slot] = True
-            n_props[r.rid] = len(props)
-            rows.append(r)
-        if not rows:
-            return
-        self.telemetry.mark_kind("verify")
-        for r in rows:
-            self._cow_tail(r)
-        mbb = _next_pow2(max(len(r.blocks) for r in rows))
-        table = np.zeros((bsz, mbb), np.int32)
-        for r in rows:
-            table[r.slot, : len(r.blocks)] = r.blocks
-        # window width bucketed to powers of two, capped at depth+1: when
-        # back-off shrinks every row's proposals, the step pays for a
-        # narrow executable instead of the full-depth window. Bounded
-        # compile: one executable per (window-bucket, table-bucket) pair.
-        t = min(_next_pow2(int(np.max(n_valid))), self.spec.depth + 1)
-        kv_state, ssm_states, greedy, n_acc, row_ok = self._verify_step(
-            self.params, self.kv.state, self._ssm_states,
-            jnp.asarray(tokens[:, :t]), jnp.asarray(ctx),
-            jnp.asarray(n_valid), jnp.asarray(table), jnp.asarray(active),
-            jnp.asarray(self._inj_mask(bsz, ((r.slot, r) for r in rows))))
-        self.kv.state = kv_state
-        if self._ssm_pos:
-            self._ssm_states = ssm_states
-        greedy = np.asarray(greedy)
-        n_acc = np.asarray(n_acc)
-        row_ok = np.asarray(row_ok)
-        now = self.clock()
-        for r in rows:
-            if not row_ok[r.slot]:
-                # quarantine: nothing the poisoned forward produced is
-                # emitted or recorded; eviction scrubs its pages (the
-                # appended window KV included) before the blocks free
-                self._evict_terminal(r, FAILED)
-                continue
-            j = int(n_acc[r.slot])
-            emitted = [int(tok) for tok in greedy[r.slot, : j + 1]]
-            r.output.extend(emitted)
-            self.decode_tokens += len(emitted)
-            if n_props[r.rid]:
-                self.spec.record(r, proposed=n_props[r.rid], accepted=j)
-            if len(r.output) >= r.max_new_tokens:
-                self.sched.finish(r, now)
-                self.finished.append(r)
+        tel = self.telemetry
+        with tel.phase("verify", rows=len(live)):
+            with tel.phase("inputs"):
+                bsz = self.max_batch
+                t = self.spec.depth + 1
+                tokens = np.zeros((bsz, t), np.int32)
+                ctx = np.zeros((bsz,), np.int32)
+                n_valid = np.zeros((bsz,), np.int32)
+                active = np.zeros((bsz,), bool)
+                n_props: Dict[int, int] = {}
+                rows: List[Request] = []
+                for r in sorted(live, key=lambda r: (r.arrival, r.rid)):
+                    if r.state != RUNNING:  # preempted by an elder's growth
+                        continue
+                    budget = r.max_new_tokens - len(r.output) - 1
+                    k = self.spec.depth_for(r, budget) if budget >= 1 else 0
+                    props = self.spec.propose(r, k) if k >= 1 else []
+                    # the verify window appends up to len(props)+1 tokens
+                    # of KV; iteration is oldest-first, so growth can only
+                    # preempt rows not yet gathered (strictly younger
+                    # requests)
+                    if props and not self.sched.ensure_blocks(
+                            r, r.length + len(props)):
+                        props = []
+                    tokens[r.slot, 0] = r.output[-1]
+                    tokens[r.slot, 1: 1 + len(props)] = props
+                    ctx[r.slot] = r.length - 1          # current KV length
+                    n_valid[r.slot] = 1 + len(props)
+                    active[r.slot] = True
+                    n_props[r.rid] = len(props)
+                    rows.append(r)
+                if not rows:
+                    return
+                tel.mark_kind("verify")
+                for r in rows:
+                    self._cow_tail(r)
+                mbb = _next_pow2(max(len(r.blocks) for r in rows))
+                table = np.zeros((bsz, mbb), np.int32)
+                for r in rows:
+                    table[r.slot, : len(r.blocks)] = r.blocks
+                # window width bucketed to powers of two, capped at
+                # depth+1: when back-off shrinks every row's proposals, the
+                # step pays for a narrow executable instead of the
+                # full-depth window. Bounded compile: one executable per
+                # (window-bucket, table-bucket) pair.
+                t = min(_next_pow2(int(np.max(n_valid))),
+                        self.spec.depth + 1)
+                args = (jnp.asarray(tokens[:, :t]), jnp.asarray(ctx),
+                        jnp.asarray(n_valid), jnp.asarray(table),
+                        jnp.asarray(active),
+                        jnp.asarray(self._inj_mask(
+                            bsz, ((r.slot, r) for r in rows))))
+            with tel.phase("launch"):
+                kv_state, ssm_states, greedy, n_acc, row_ok = \
+                    self._verify_step(self.params, self.kv.state,
+                                      self._ssm_states, *args)
+            self.kv.state = kv_state
+            if self._ssm_pos:
+                self._ssm_states = ssm_states
+            with tel.phase("wait"):
+                greedy = np.asarray(greedy)
+                n_acc = np.asarray(n_acc)
+                row_ok = np.asarray(row_ok)
+            with tel.phase("finish"):
+                now = self.clock()
+                for r in rows:
+                    if not row_ok[r.slot]:
+                        # quarantine: nothing the poisoned forward produced
+                        # is emitted or recorded; eviction scrubs its pages
+                        # (the appended window KV included) before the
+                        # blocks free
+                        self._evict_terminal(r, FAILED)
+                        continue
+                    j = int(n_acc[r.slot])
+                    emitted = [int(tok) for tok in greedy[r.slot, : j + 1]]
+                    r.output.extend(emitted)
+                    self.decode_tokens += len(emitted)
+                    if n_props[r.rid]:
+                        self.spec.record(r, proposed=n_props[r.rid],
+                                         accepted=j)
+                    if len(r.output) >= r.max_new_tokens:
+                        self.sched.finish(r, now)
+                        self.finished.append(r)
 
     def _scrub_preempted(self, victim: Request) -> None:
         """Zero a preemption victim's pages before the allocator reuses
@@ -1317,26 +1359,42 @@ class Engine:
     # ------------------------------------------------------------------
 
     def _decode_batch(self, live: List[Request]) -> None:
-        cfg = self.cfg
         if not live:
             return
-        self.telemetry.mark_kind("decode")
-        bsz = self.max_batch
-        tokens = np.zeros((bsz, 1), np.int32)
-        lengths = np.zeros((bsz,), np.int32)
-        active = np.zeros((bsz,), bool)
-        max_blocks = max(len(r.blocks) for r in live)
-        table = np.zeros((bsz, max_blocks), np.int32)
-        for r in live:
-            tokens[r.slot, 0] = r.output[-1]
-            lengths[r.slot] = r.length - 1          # current KV length
-            active[r.slot] = True
-            table[r.slot, : len(r.blocks)] = r.blocks
-        tokens = jnp.asarray(tokens)
-        lengths = jnp.asarray(lengths)
-        table = jnp.asarray(table)
-        active = jnp.asarray(active)
+        tel = self.telemetry
+        tel.mark_kind("decode")
+        with tel.phase("decode", rows=len(live),
+                       kv_tokens=sum(r.length - 1 for r in live)):
+            with tel.phase("inputs"):
+                bsz = self.max_batch
+                tokens = np.zeros((bsz, 1), np.int32)
+                lengths = np.zeros((bsz,), np.int32)
+                active = np.zeros((bsz,), bool)
+                max_blocks = max(len(r.blocks) for r in live)
+                table = np.zeros((bsz, max_blocks), np.int32)
+                for r in live:
+                    tokens[r.slot, 0] = r.output[-1]
+                    lengths[r.slot] = r.length - 1      # current KV length
+                    active[r.slot] = True
+                    table[r.slot, : len(r.blocks)] = r.blocks
+                args = (jnp.asarray(tokens), jnp.asarray(lengths),
+                        jnp.asarray(table), jnp.asarray(active))
+            with tel.phase("launch"):
+                logits = self._legacy_forward(*args)
+            with tel.phase("wait"):
+                next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+                # legacy path quarantines on the host (no injection mask
+                # here; the flag still catches organically-poisoned
+                # weights/state)
+                row_ok = np.asarray(jnp.all(
+                    jnp.isfinite(logits.astype(jnp.float32)), axis=-1))
+            with tel.phase("finish"):
+                self._finish_step(live, next_tokens, row_ok=row_ok)
 
+    def _legacy_forward(self, tokens, lengths, table, active):
+        """The legacy decode forward, one eager dispatch per layer; returns
+        the (max_batch, vocab) logits."""
+        cfg = self.cfg
         x = jnp.take(self.params["embed"], tokens, axis=0)
         attn_layer = 0
         for i, kind in enumerate(cfg.layer_kinds()):
@@ -1369,13 +1427,7 @@ class Engine:
             w = self.params["embed"].T
         else:
             w = self.params["head"]
-        logits = L.dense(x, w)[:, 0]
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
-        # legacy path quarantines on the host (no injection mask here;
-        # the flag still catches organically-poisoned weights/state)
-        row_ok = np.asarray(jnp.all(
-            jnp.isfinite(logits.astype(jnp.float32)), axis=-1))
-        self._finish_step(live, next_tokens, row_ok=row_ok)
+        return L.dense(x, w)[:, 0]
 
     def _paged_attn(self, x, p, attn_layer: int, table, lengths, active):
         cfg = self.cfg
@@ -1428,70 +1480,72 @@ class Engine:
     def step(self) -> None:
         # telemetry wraps each segment below without reordering it: every
         # hook is host-side, so a telemetry-off step executes exactly the
-        # code it always did (phase() is a shared null context then)
+        # code it always did, plus profiler spans that are inert unless a
+        # profiler session is recording
         tel = self.telemetry
-        tel.step_begin(self.steps)
-        # fault injection + deadline sweep run before admission so a
-        # stormed/cancelled request never occupies a slot this step
-        with tel.phase("sweep"):
-            if self.faults is not None:
-                self.faults.on_step_begin(self)
-            if self._deadlines_armed:
-                self._sweep_deadlines(self.clock())
-        with tel.phase("schedule"):
-            admitted = self.sched.admit(self.clock())
-            for r in admitted:
-                if self._prefix is not None:
-                    self.prefix_lookups += 1
-                    if r.cached_tokens:
-                        self.prefix_hits += 1
-                        self.prefix_tokens_reused += r.cached_tokens
-                # a cache hit resumes the recurrent state from the matched
-                # node's snapshot; everything else starts the slot from zero
-                if r.cached_tokens and self._ssm_pos:
-                    self._restore_ssm_slot(r)
-                elif self.prefill_chunk is not None:
-                    self._zero_ssm_slot(r.slot)
-        t0 = self.clock()
-        with tel.phase("dispatch"):
-            if self.prefill_chunk is None:
-                if admitted:
-                    self._prefill(admitted)
-            else:
-                self._prefill_chunk_tick()
-        self.prefill_time += self.clock() - t0
-        # grow each decoding request's block table for this step's append;
-        # under pressure this preempts strictly-younger request(s) — so
-        # re-check states after the loop — and a request that could only
-        # grow by evicting an elder sits this step out instead
-        with tel.phase("schedule"):
-            deferred = set()
-            for r in self.sched.decode_candidates():
-                if r.state == RUNNING and \
-                        not self.sched.ensure_blocks(r, r.length):
-                    deferred.add(r.rid)
-            live = [r for r in self.sched.running
-                    if r is not None and r.state == RUNNING
-                    and r.rid not in deferred]
-        t0 = self.clock()
-        with tel.phase("dispatch"):
-            if self.mode != "fused":
-                self._decode_batch(live)
-            elif self.spec is not None:
-                self._decode_spec(live)
-            else:
-                self._decode_fused(live)
-        self.decode_time += self.clock() - t0
-        # fenced mode: attribute async device time to the step that
-        # dispatched it (paper-style module-wise timing at smoke scale —
-        # serializes the dispatch pipeline, so never on by default)
-        if tel.enabled and tel.fenced:
-            with tel.phase("sync"):
-                jax.block_until_ready((self.kv.state, self._ssm_states))
-        # a NaN plan is good for exactly one step's forward, armed or not
-        self._nan_plan = None
-        self.steps += 1
-        tel.step_end(self)
+        with tel.phase("step", step=self.steps):
+            tel.step_begin(self.steps)
+            # fault injection + deadline sweep run before admission so a
+            # stormed/cancelled request never occupies a slot this step
+            with tel.phase("sweep"):
+                if self.faults is not None:
+                    self.faults.on_step_begin(self)
+                if self._deadlines_armed:
+                    self._sweep_deadlines(self.clock())
+            with tel.phase("schedule"):
+                admitted = self.sched.admit(self.clock())
+                for r in admitted:
+                    if self._prefix is not None:
+                        self.prefix_lookups += 1
+                        if r.cached_tokens:
+                            self.prefix_hits += 1
+                            self.prefix_tokens_reused += r.cached_tokens
+                    # a cache hit resumes the recurrent state from the matched
+                    # node's snapshot; everything else starts the slot from zero
+                    if r.cached_tokens and self._ssm_pos:
+                        self._restore_ssm_slot(r)
+                    elif self.prefill_chunk is not None:
+                        self._zero_ssm_slot(r.slot)
+            t0 = self.clock()
+            with tel.phase("dispatch"):
+                if self.prefill_chunk is None:
+                    if admitted:
+                        self._prefill(admitted)
+                else:
+                    self._prefill_chunk_tick()
+            self.prefill_time += self.clock() - t0
+            # grow each decoding request's block table for this step's
+            # append; under pressure this preempts strictly-younger
+            # request(s) — so re-check states after the loop — and a request
+            # that could only grow by evicting an elder sits this step out
+            with tel.phase("schedule"):
+                deferred = set()
+                for r in self.sched.decode_candidates():
+                    if r.state == RUNNING and \
+                            not self.sched.ensure_blocks(r, r.length):
+                        deferred.add(r.rid)
+                live = [r for r in self.sched.running
+                        if r is not None and r.state == RUNNING
+                        and r.rid not in deferred]
+            t0 = self.clock()
+            with tel.phase("dispatch"):
+                if self.mode != "fused":
+                    self._decode_batch(live)
+                elif self.spec is not None:
+                    self._decode_spec(live)
+                else:
+                    self._decode_fused(live)
+            self.decode_time += self.clock() - t0
+            # fenced mode: attribute async device time to the step that
+            # dispatched it (paper-style module-wise timing at smoke scale —
+            # serializes the dispatch pipeline, so never on by default)
+            if tel.enabled and tel.fenced:
+                with tel.phase("sync"):
+                    jax.block_until_ready((self.kv.state, self._ssm_states))
+            # a NaN plan is good for exactly one step's forward, armed or not
+            self._nan_plan = None
+            self.steps += 1
+            tel.step_end(self)
 
     def _progress_key(self):
         """Snapshot of everything that changes when any request advances:

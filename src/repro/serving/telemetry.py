@@ -1,10 +1,11 @@
 """Serving telemetry: request-lifecycle tracing, a per-step phase
-timeline, and an exportable metrics registry.
+timeline, profiler spans of the engine's step phases, and an exportable
+metrics registry.
 
 The paper's contribution is *dissecting* runtime — module-wise and
 phase-wise breakdowns that explain where wall-clock goes (§III-B,
 Tables V-XI) — and this module is the serving-side apparatus for the
-same question. Three pillars, all host-side:
+same question. Four pillars:
 
   * **Request-lifecycle spans.** Every request owns a span tree on the
     trace timeline: ``queued`` (submit → admission), ``prefill`` (per
@@ -15,23 +16,32 @@ same question. Three pillars, all host-side:
     ``queue_drop``). :meth:`Telemetry.export_chrome` writes the whole
     timeline as Chrome-trace JSON — load it in ``chrome://tracing`` or
     https://ui.perfetto.dev — with one track per request plus an engine
-    track of step spans and pool/queue counter series.
+    track of step spans and pool/queue counter series. Host clock
+    (:attr:`Telemetry.clock`), on its own epoch.
 
   * **Per-step phase timeline.** A bounded ring buffer of per-step
     records: the host-side phase split (``sweep`` — faults + deadline
-    sweep, ``schedule`` — admission + block growth, ``dispatch`` —
-    building step inputs, the jitted call and host materialization of
-    its outputs, ``sync`` — the explicit fence of fenced mode), the
-    traced-step kinds the step dispatched (``decode``/``chunk``/
-    ``verify``/``prefill``), batch occupancy, the block-pool occupancy
-    split (owned / cached_reclaimable / free), waiting-queue depth and
-    speculative proposed/accepted counts. Phase durations accumulate in
-    a :class:`repro.core.perfscope.Timer`, so ``telemetry.timer.table()``
-    prints the same per-region breakdown trainings' perfscope does —
-    train and serve share one timing idiom. ``fenced=True`` adds a
-    ``block_until_ready`` fence on the post-step state inside the
+    sweep, ``schedule`` — admission + block growth, ``dispatch`` — the
+    chunk tick and the decode call, ``sync`` — the explicit fence of
+    fenced mode, and inside ``dispatch`` each forward — ``chunk`` /
+    ``decode`` / ``verify`` / ``prefill`` — with its ``inputs``,
+    ``launch``, ``wait`` and ``finish``), the traced-step kinds the step
+    dispatched, batch occupancy, the block-pool occupancy split (owned /
+    cached_reclaimable / free), waiting-queue depth and speculative
+    proposed/accepted counts. Phase durations also add up into one
+    running total per phase (``timeline.phase_totals_s``), so a
+    long-running server keeps a fixed amount per phase. ``fenced=True``
+    adds a ``block_until_ready`` fence on the post-step state inside the
     ``sync`` phase (the paper's torch.profiler-style attribution mode:
     use at smoke scale, it serializes the async dispatch pipeline).
+
+  * **Profiler spans.** Every :meth:`Telemetry.phase` entry, enabled or
+    not, is also a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<phase>``. A TraceAnnotation is inert unless a profiler
+    session is recording; when one is, the spans land in the profiler's
+    own trace on the clock of its device events, so a device idle gap
+    can be charged to the engine phase the host was in
+    (docs/observability.md, "Profiler spans").
 
   * **Metrics registry.** Counters, gauges and histograms with a stable
     machine-readable snapshot: :meth:`Telemetry.snapshot` returns the
@@ -45,20 +55,22 @@ invisible to the device. Every hook is host-side; enabling telemetry
 adds **zero jit dispatches and no new traced arguments**, the engine's
 ``trace_counts`` is identical telemetry-on vs -off, and greedy output
 is bitwise-identical. A disabled :class:`Telemetry` (the engine
-default) reduces every hook to one predicate check. Fault injection
-(serving/faults.py) logs its actions through :meth:`chaos_action`, so a
-chaos run's squeezes/cancels/NaN-quarantines land on the same timeline
-as the victims' spans — visually alignable in the trace viewer.
+default) keeps nothing in memory: every hook is one predicate check,
+and :meth:`~Telemetry.phase` opens only its inert profiler span. Fault
+injection (serving/faults.py) logs its actions through
+:meth:`chaos_action`, so a chaos run's squeezes/cancels/NaN-quarantines
+land on the same timeline as the victims' spans — visually alignable in
+the trace viewer.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from collections import Counter, deque
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.core.perfscope import Timer
+from jax.profiler import TraceAnnotation
+
 # shared with Engine.stats(): both report percentiles through ONE
 # definition (core/stats.py) so histogram snapshots and SLO stats can
 # never drift on empty/singleton edge cases (pinned by tests)
@@ -72,7 +84,9 @@ __all__ = ["Telemetry", "MetricsRegistry", "SCHEMA_VERSION"]
 #: superset, not equality).
 SCHEMA_VERSION = 1
 
-#: Engine-step phase names, in execution order (see module docstring).
+#: Engine-step phase names, in execution order (see module docstring);
+#: ``timeline.phase_totals_s`` always carries these, and any other phase
+#: entered (``step``, the forwards and their stages) beside them.
 PHASES = ("sweep", "schedule", "dispatch", "sync")
 
 #: Hard cap on retained Chrome-trace events: tracing a very long run
@@ -80,44 +94,41 @@ PHASES = ("sweep", "schedule", "dispatch", "sync")
 #: instead of growing without bound.
 _EVENTS_CAP = 500_000
 
-#: Shared no-op context for the disabled-telemetry ``phase()`` path: no
-#: generator frame, no clock reads — one predicate check per phase.
-_NULL_PHASE = contextlib.nullcontext()
-
 #: Zeroed per-phase accumulator template; ``.copy()``-ed per step record
 #: (cheaper than re-running ``dict.fromkeys`` in the step_begin hook).
 _PHASE_ZEROS = dict.fromkeys(PHASES, 0.0)
 
 
 class _PhaseCtx:
-    """Hand-rolled context manager for one phase name, cached per
-    Telemetry instance: the contextlib generator machinery costs several
-    microseconds per use, which at ~5 phase regions per engine step is
-    the difference between telemetry overhead in the noise and telemetry
-    overhead in the step budget. Not re-entrant per name — engine phases
-    never nest the same name (they accumulate across separate entries)."""
+    """Hand-rolled context manager for one entry of a phase: its profiler
+    span, plus the host-clock duration into the current step record and
+    the phase's running total (the contextlib generator machinery costs
+    several microseconds per use, about 16 phase entries run per engine
+    step)."""
 
-    __slots__ = ("tel", "name", "t0", "rec")
+    __slots__ = ("tel", "name", "span", "t0")
 
-    def __init__(self, tel: "Telemetry", name: str):
+    def __init__(self, tel: "Telemetry", name: str, span):
         self.tel = tel
         self.name = name
+        self.span = span
         self.t0 = 0.0
-        # bind the perfscope record list once; Telemetry.reset() swaps
-        # the Timer out and clears the ctx cache, so this never dangles
-        self.rec = tel.timer.records[name]
 
     def __enter__(self):
+        self.span.__enter__()
         self.t0 = self.tel.clock()
         return self
 
     def __exit__(self, *exc):
         tel = self.tel
+        name = self.name
         dt = tel.clock() - self.t0
         cur = tel._cur
         if cur is not None:
-            cur["phases"][self.name] += dt
-        self.rec.append(dt)
+            phases = cur["phases"]
+            phases[name] = phases.get(name, 0.0) + dt
+        tel._phase_totals[name] = tel._phase_totals.get(name, 0.0) + dt
+        self.span.__exit__(*exc)
         return False
 
 
@@ -183,9 +194,10 @@ class Telemetry:
     """Observability hub for one serving :class:`~repro.serving.engine.
     Engine` (bound via :meth:`bind`; the engine does this in its
     constructor). ``enabled=False`` (the engine default) turns every
-    hook into a single predicate check; chaos actions are the one
-    exception — they are recorded regardless, because the post-run
-    action log must exist even when tracing is off.
+    hook into a single predicate check, and :meth:`phase` into its bare
+    profiler span; chaos actions are the one exception — they are
+    recorded regardless, because the post-run action log must exist
+    even when tracing is off.
 
     ``clock`` defaults to ``time.perf_counter`` and is deliberately
     independent of the engine's scheduling clock: tests drive engines
@@ -201,7 +213,7 @@ class Telemetry:
         self.fenced = fenced
         self.clock = clock
         self.registry = MetricsRegistry()
-        self.timer = Timer()            # perfscope idiom: phase regions
+        self._phase_totals: Dict[str, float] = {}   # seconds per phase
         self.timeline: deque = deque(maxlen=timeline_cap)
         self.events: List[dict] = []    # eagerly-built events (chaos track)
         self.events_dropped = 0
@@ -210,7 +222,6 @@ class Telemetry:
         self._engine = None
         self._epoch = clock()
         self._cur: Optional[dict] = None        # current step record
-        self._phase_ctxs: Dict[str, _PhaseCtx] = {}
         self._step_names: Dict[Tuple[str, ...], str] = {}
         self._kind_keys: Dict[str, str] = {}
         self._term_keys: Dict[str, str] = {}
@@ -385,18 +396,18 @@ class Telemetry:
                      "kinds": [], "phases": _PHASE_ZEROS.copy(),
                      "spec_proposed": 0, "spec_accepted": 0}
 
-    def phase(self, name: str):
-        """Time one host-side phase of the current engine step; phases
-        may be entered more than once per step (durations accumulate)
-        and always also land in :attr:`timer` (perfscope regions).
-        Disabled telemetry returns a shared null context; enabled
-        telemetry a cached per-name :class:`_PhaseCtx`."""
+    def phase(self, name: str, **args):
+        """One host-side phase of the current engine step, and its
+        profiler span ``engine.<name>`` carrying ``args``. Phases may be
+        entered more than once per step (durations accumulate); a phase
+        never nests inside itself. Disabled telemetry returns the bare
+        span, inert unless a profiler session is recording; enabled
+        telemetry also times the phase into the step record and the
+        phase's running total."""
+        span = TraceAnnotation("engine." + name, **args)
         if not self.enabled:
-            return _NULL_PHASE
-        ctx = self._phase_ctxs.get(name)
-        if ctx is None:
-            ctx = self._phase_ctxs[name] = _PhaseCtx(self, name)
-        return ctx
+            return span
+        return _PhaseCtx(self, name, span)
 
     def mark_kind(self, kind: str) -> None:
         """Record a traced-step dispatch kind for the current step
@@ -473,8 +484,7 @@ class Telemetry:
     # ------------------------------------------------------------------
 
     def timeline_summary(self) -> Dict[str, Any]:
-        phase_totals = {name: float(sum(self.timer.records.get(name, ())))
-                        for name in PHASES}
+        phase_totals = dict(_PHASE_ZEROS, **self._phase_totals)
         kinds: Counter = Counter()
         for rec in self.timeline:
             for k in rec["kinds"]:
@@ -582,8 +592,7 @@ class Telemetry:
         ``Engine.reset_stats`` so a benchmark's measured pass starts
         with empty telemetry the same way it starts with empty stats."""
         self.registry.reset()
-        self.timer = Timer()
-        self._phase_ctxs.clear()    # ctxs bind the replaced Timer's lists
+        self._phase_totals.clear()
         self.timeline.clear()
         self.events = []
         self._step_recs = []

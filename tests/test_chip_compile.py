@@ -10,6 +10,7 @@ Nothing runs, so they say nothing about results or speed.
 The topology is described inside a module fixture (only one process at a
 time may load the TPU library, and pytest workers import every test file).
 """
+import functools
 import os
 import sys
 
@@ -111,3 +112,31 @@ def test_fused_decode_step_fits_v5e(one_chip, on_tpu, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     need = chip_smoke.step_bytes(compiled)
     assert need < V5E_HBM, (need, compiled.memory_analysis())
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_prefix"])
+def test_paged_kernel_lowers_with_its_name(one_chip, kernel):
+    """Each paged read carries its own name into the TPU custom call, so
+    the device trace says which of the two ran; the call stays a
+    ``tpu_custom_call``, which is what the benchmark's kernel time
+    matches."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import flash_decode as fd
+
+    b, t, h, kv, d, bs, n_blocks, mb = 2, 4, 8, 2, 64, 16, 32, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    page = sds((n_blocks, bs, kv, d), jnp.bfloat16)
+    table, lengths = sds((b, mb), jnp.int32), sds((b,), jnp.int32)
+    if kernel == "paged_decode":
+        read, q = fd.paged_flash_decode_partial, sds((b, h, d), jnp.bfloat16)
+    else:
+        read = fd.paged_flash_prefix_partial
+        q = sds((b, t, h, d), jnp.bfloat16)
+    fn = functools.partial(read, impl="pallas", interpret=False)
+    text = jax.jit(fn).lower(q, page, page, table, lengths).as_text()
+    assert f'kernel_name = "{kernel}"' in text
+    assert "@tpu_custom_call" in text

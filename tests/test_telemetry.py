@@ -1,8 +1,11 @@
 """Serving telemetry: the zero-overhead-off contract (identical greedy
-tokens and trace_counts with telemetry on vs. off), request-lifecycle
-span coverage on a mixed preemption/speculation/prefix-cache trace,
+tokens and trace_counts with telemetry on vs. off, and with a profiler
+session recording or not), the engine's profiler spans (nesting, order
+and arguments, read back from a real profile), request-lifecycle span
+coverage on a mixed preemption/speculation/prefix-cache trace,
 Chrome-trace export validity, the bounded step timeline, chaos-action
 mirroring, and the snapshot schema-stability guarantee that CI pins."""
+import glob
 import json
 
 import jax
@@ -13,8 +16,8 @@ from repro.configs import get_config
 from repro.data.pipeline import shared_prefix_requests
 from repro.models.lm import LM
 from repro.serving.engine import Engine, Rejected, Request
-from repro.serving.telemetry import (SCHEMA_VERSION, MetricsRegistry,
-                                     Telemetry, _NULL_PHASE)
+from repro.serving.telemetry import (PHASES, SCHEMA_VERSION,
+                                     MetricsRegistry, Telemetry)
 
 
 @pytest.fixture(scope="module")
@@ -87,17 +90,118 @@ def test_telemetry_on_off_token_and_trace_parity(cfg, params):
     assert traces[True] == traces[False]
 
 
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler session; returns its result and the
+    ``engine.*`` host spans of the profile as (start_ns, end_ns, name,
+    args), read back with ``ProfileData``."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+              dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("engine.")]
+    return out, spans
+
+
+def _span_tree(spans):
+    """Nest spans by containment: [(name, args, children)] at the top."""
+    root = ("", {}, [])
+    stack = [(float("inf"), root)]
+    for a, b, name, args in sorted(spans, key=lambda s: (s[0], -s[1])):
+        while a >= stack[-1][0]:
+            stack.pop()
+        node = (name, args, [])
+        stack[-1][1][2].append(node)
+        stack.append((b, node))
+    return root[2]
+
+
+STAGES = ["engine.inputs", "engine.launch", "engine.wait", "engine.finish"]
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_engine_step_spans_nest_in_order(cfg, params, tmp_path, on):
+    """Each engine step is one ``engine.step`` span holding its phases in
+    execution order; the chunk and the decode forward sit in the two
+    dispatches, each with its four stages in order, and carry their
+    arguments. Enabled or not, the spans are the same."""
+    prompts = _prompts(cfg, (12, 7))
+    eng = Engine(cfg, params, max_batch=2, n_blocks=32, block_size=8,
+                 prefill_chunk=5, telemetry=on)
+    _, spans = _profiled(tmp_path, lambda: _drain(eng, prompts, max_new=4))
+    steps = _span_tree(spans)
+    assert [s[0] for s in steps] == ["engine.step"] * len(steps)
+    assert len(steps) >= 6
+    chunks, both = [], 0
+    for _, args, kids in steps:
+        assert "step" in args
+        names = [k[0] for k in kids]
+        assert names == ["engine.sweep", "engine.schedule",
+                         "engine.dispatch", "engine.schedule",
+                         "engine.dispatch"]
+        forwards = [f for k in kids if k[0] == "engine.dispatch"
+                    for f in k[2]]
+        for name, fargs, stages in forwards:
+            assert name in ("engine.chunk", "engine.decode")
+            assert [s[0] for s in stages] == STAGES
+            assert all(s[2] == [] for s in stages)
+            if name == "engine.chunk":
+                chunks.append((fargs["rid"], fargs["start"], fargs["n"]))
+            else:
+                assert 1 <= fargs["rows"] <= 2 and fargs["kv_tokens"] > 0
+        kinds = [f[0] for f in forwards]
+        assert kinds in ([], ["engine.chunk"], ["engine.decode"],
+                         ["engine.chunk", "engine.decode"])
+        both += kinds == ["engine.chunk", "engine.decode"]
+    assert both > 0
+    # the chunks of each request tile its prompt from 0, in order
+    for rid, p in enumerate(prompts):
+        mine = [(s, n) for r, s, n in chunks if r == rid]
+        assert [s for s, _ in mine] == list(range(0, len(p), 5))
+        assert sum(n for _, n in mine) == len(p)
+    if on:
+        totals = eng.telemetry.timeline_summary()["phase_totals_s"]
+        for name in ("step", "chunk", "decode", "inputs", "launch", "wait",
+                     "finish"):
+            assert totals[name] > 0.0
+
+
+def test_profiler_session_token_and_trace_parity(cfg, params, tmp_path):
+    """A recording profiler session changes no traced program and no
+    token: the spans add no dispatch and no traced argument."""
+    prompts = _prompts(cfg, (12, 7, 9))
+    outs, traces = {}, {}
+    for traced in (False, True):
+        eng = Engine(cfg, params, max_batch=2, n_blocks=64, block_size=8,
+                     prefill_chunk=5)
+        if traced:
+            outs[traced], _ = _profiled(tmp_path, lambda: _drain(eng, prompts))
+        else:
+            outs[traced] = _drain(eng, prompts)
+        traces[traced] = dict(eng.trace_counts)
+    assert outs[True] == outs[False]
+    assert traces[True] == traces[False]
+
+
 def test_disabled_phase_is_shared_null_context(cfg, params):
+    """Disabled telemetry keeps nothing in memory: a phase is its bare
+    profiler span (inert unless a profiler session records), with no
+    clock read, no step record and no running total."""
     tel = Telemetry(enabled=False)
-    assert tel.phase("schedule") is _NULL_PHASE
-    assert tel.phase("dispatch") is _NULL_PHASE
+    for name in ("schedule", "dispatch"):
+        assert type(tel.phase(name)) is jax.profiler.TraceAnnotation
     eng = Engine(cfg, params, max_batch=2, n_blocks=16, block_size=8)
     _drain(eng, _prompts(cfg, (8,)), max_new=3)
     # disabled telemetry collected nothing at all
     tel = eng.telemetry
     assert not tel.enabled
     assert tel.events == [] and len(tel.timeline) == 0
-    assert tel.timer.records == {}
+    assert tel._phase_totals == {}
+    assert tel.timeline_summary()["phase_totals_s"] == \
+        dict.fromkeys(PHASES, 0.0)
     assert tel.registry.snapshot()["counters"] == {}
 
 
