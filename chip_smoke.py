@@ -266,10 +266,11 @@ def check_spread(eng, devices) -> None:
     from collections import Counter
     import jax
 
-    k = eng.kv.state["k"]
-    heads = {s.device: s.data.shape[3] for s in k.addressable_shards}
+    k = eng.kv.state["k"]         # (L, n_blocks, block, K*hd)
+    hd = eng.kv_cfg.head_dim
+    heads = {s.device: s.data.shape[3] // hd for s in k.addressable_shards}
     if set(heads) != set(devices) or set(heads.values()) != {
-            k.shape[3] // len(devices)}:
+            eng.kv_cfg.n_kv_heads // len(devices)}:
         raise RuntimeError(f"kv pool {k.shape} not split over the kv "
                            f"heads of {len(devices)} devices: {heads}")
     # the tied embedding and the norms stay whole on every device by

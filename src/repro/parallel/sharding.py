@@ -131,11 +131,14 @@ class ShardCtx:
         if kind == "tokens":
             return P(self._dp(shape[0]), None)
         if kind == "kv_pool":
-            # paged serving storage (L, n_blocks, block, K, hd): split the
-            # KV-head axis so every shard owns K/tp heads of every page;
-            # block tables and the allocator stay host-global, and the
-            # engine's scatters/gathers are shard-local by construction
-            return P(None, None, None, self._mdl(shape[3]), None)
+            # paged serving storage (L, n_blocks, block, K*hd), kv heads
+            # folded into the last axis (int8 scales: (L, n_blocks, block,
+            # K)): split that axis only where the degree divides the kv-head
+            # count, so every shard owns K/tp whole heads of every page, and
+            # replicate otherwise; block tables and the allocator stay
+            # host-global, and the engine's scatters/gathers are shard-local
+            # by construction
+            return P(None, None, None, self._mdl(self.cfg.n_kv_heads))
         raise KeyError(kind)
 
     def constrain(self, x: jax.Array, kind: str) -> jax.Array:
@@ -183,9 +186,10 @@ def make_serving_ctx(cfg: ArchConfig, mesh: Mesh) -> ShardCtx:
     agnostic), so there is no data axis — the batch is replicated and every
     collective the steps emit is a model-axis psum/all-gather at the
     row-parallel seams (wo, MLP down-proj, logits). Attention is pinned to
-    head mode: the paged KV pool splits on the KV-head axis (``kv_pool``)
-    and each shard computes complete (o, m, l) partials for its own heads
-    — LSE-merging via merge_partials stays shard-local, never a collective.
+    head mode: the paged KV pool splits its folded KV-head axis on head
+    boundaries (``kv_pool``) and each shard computes complete (o, m, l)
+    partials for its own heads — LSE-merging via merge_partials stays
+    shard-local, never a collective.
     Axes that don't divide the TP degree (e.g. 4 smoke KV heads at tp=8)
     degrade to replication per tensor, not an error, exactly like training.
     """

@@ -15,10 +15,20 @@ The storage layer is split in two:
   * the :class:`PagedKVCache` convenience wrapper that owns a state pytree
     and mutates it in place for the host-driven legacy path and tests.
 
-Int8KV (LightLLM) is supported by storing quantized KV + per-(block, head)
-scales, doubling token capacity. Scatters use ``mode="drop"`` so an
-out-of-range block id acts as a *null write* — the engine routes inactive
-batch slots to block id ``n_blocks`` to mask their appends.
+Every configuration gets one storage layout, (L, n_blocks, block, K*hd)
+per k/v: every attention layer's pages stacked on axis 0, and a token's kv
+heads side by side on the last (lane) axis, so a page row is lane-dense
+for any head size. The paged reads address it in place by (layer, block)
+(kernels/flash_decode.py) and the steps scatter into it in place, so no
+step copies or slices the pool; the per-head (..., K, hd) view is a
+reshape taken only where a reader needs heads apart (:func:`gather`, the
+XLA read, tests).
+
+Int8KV (LightLLM) is supported by storing quantized KV + per-(block,
+position, head) float32 scales, (L, n_blocks, block, K), doubling token
+capacity. Scatters use ``mode="drop"`` so an out-of-range block id acts as
+a *null write* — the engine routes inactive batch slots to block id
+``n_blocks`` to mask their appends.
 """
 from __future__ import annotations
 
@@ -245,22 +255,23 @@ def quant_decode(q: jax.Array, scale: Optional[jax.Array],
 
 def init_state(cfg: PagedKVConfig, dtype=jnp.bfloat16,
                sharding=None) -> Dict[str, jax.Array]:
-    """Fresh storage pytree: k/v (L, n_blocks, block, K, hd) (+ scales).
+    """Fresh storage pytree: k/v (L, n_blocks, block, K*hd), heads folded
+    into the last axis (+ int8 scales (L, n_blocks, block, K)).
 
     ``sharding`` (optional ``jax.sharding.Sharding``) places every leaf —
     the model-parallel serving engine passes a NamedSharding that splits
-    the KV-head axis over the mesh's ``model`` axis, so each shard owns
-    ``K / tp`` heads of every page and all writes/reads stay shard-local
-    (the scale leaves share the same spec: their K axis lines up).
+    the folded last axis over the mesh's ``model`` axis on head
+    boundaries, so each shard owns ``K / tp`` whole heads of every page
+    and all writes/reads stay shard-local (the scale leaves share the same
+    spec: their K axis lines up).
     """
     store_dtype = jnp.int8 if cfg.kv_quant == "int8" else dtype
-    shape = (cfg.n_layers, cfg.n_blocks, cfg.block_size,
-             cfg.n_kv_heads, cfg.head_dim)
+    rows = (cfg.n_layers, cfg.n_blocks, cfg.block_size)
+    shape = rows + (cfg.n_kv_heads * cfg.head_dim,)
     state = {"k": jnp.zeros(shape, store_dtype),
              "v": jnp.zeros(shape, store_dtype)}
     if cfg.kv_quant == "int8":
-        sshape = (cfg.n_layers, cfg.n_blocks, cfg.block_size,
-                  cfg.n_kv_heads, 1)
+        sshape = rows + (cfg.n_kv_heads,)
         state["k_scale"] = jnp.ones(sshape, jnp.float32)
         state["v_scale"] = jnp.ones(sshape, jnp.float32)
     if sharding is not None:
@@ -275,16 +286,17 @@ def write_prefill(state: Dict[str, jax.Array], kv_quant: str,
     into the sequence's blocks (T padded up to a block multiple)."""
     k, v = layer_kv
     bs = state["k"].shape[2]
-    t = k.shape[1]
+    n_l, t = k.shape[:2]
     pad = (-t) % bs
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     nb = k.shape[1] // bs
-    kq = k.reshape(k.shape[0], nb, bs, *k.shape[2:])
-    vq = v.reshape(v.shape[0], nb, bs, *v.shape[2:])
-    kq, ks = quant_encode(kq, kv_quant)
-    vq, vs = quant_encode(vq, kv_quant)
+    kq, ks = quant_encode(k, kv_quant)
+    vq, vs = quant_encode(v, kv_quant)
+    # (L, T, K, hd | 1) -> the pool's (L, nb, block, K*hd | K) rows
+    kq, vq, ks, vs = (None if a is None else a.reshape(n_l, nb, bs, -1)
+                      for a in (kq, vq, ks, vs))
     ids = jnp.asarray(np.asarray(block_ids)[:nb], jnp.int32)
     out = dict(state)
     out["k"] = state["k"].at[:, ids].set(kq.astype(state["k"].dtype),
@@ -320,9 +332,11 @@ def write_token_encoded(state: Dict[str, jax.Array],
                         block_ids: jax.Array, offsets: jax.Array
                         ) -> Dict[str, jax.Array]:
     """Like :func:`write_token` but with storage-ready values: ``enc`` holds
-    already-encoded k/v (L, B, K, hd) (+ scales). Lets a caller that needed
-    the quantized form anyway (the fused decode step attends to the fresh
-    token as stored) skip a second quant_encode pass."""
+    already-encoded k/v (L, B, K, hd) (+ scales (L, B, K, 1)). Lets a
+    caller that needed the quantized form anyway (the fused decode step
+    attends to the fresh token as stored) skip a second quant_encode pass.
+    Each of the L*B values lands as one (K*hd) row (K for a scale) at
+    (layer, block, offset): a scatter into the pool in place."""
     n_l, bsz = enc["k"].shape[0], enc["k"].shape[1]
     li = jnp.repeat(jnp.arange(n_l), bsz)
     bi = jnp.tile(block_ids, n_l)
@@ -330,8 +344,8 @@ def write_token_encoded(state: Dict[str, jax.Array],
     out = dict(state)
     for key in enc:
         out[key] = state[key].at[li, bi, oi].set(
-            enc[key].reshape(-1, *enc[key].shape[2:]).astype(
-                state[key].dtype), mode="drop")
+            enc[key].reshape(n_l * bsz, -1).astype(state[key].dtype),
+            mode="drop")
     return out
 
 
@@ -426,9 +440,10 @@ def copy_block(state: Dict[str, jax.Array], src: int, dst: int
 
 
 def gather(state: Dict[str, jax.Array], layer: int, block_table: jax.Array,
-           dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
+           n_kv_heads: int, dtype=jnp.bfloat16
+           ) -> Tuple[jax.Array, jax.Array]:
     """Dense per-batch view: block_table (B, max_blocks) int32 ->
-    k,v (B, max_blocks*block, K, hd). Dense 128-aligned block gather.
+    k,v (B, max_blocks*block, K, hd), the folded rows viewed per head.
     Legacy-path only; the fused step reads pages through the block table.
 
     Out-of-range table entries read as ZEROS: XLA's gather clamps indices,
@@ -440,12 +455,16 @@ def gather(state: Dict[str, jax.Array], layer: int, block_table: jax.Array,
     nb = state["k"].shape[1]
     in_range = (table >= 0) & (table < nb)       # (B, MB)
     safe = jnp.where(in_range, table, 0)
-    kq = state["k"][layer][safe]                 # (B, MB, bs, K, hd)
-    vq = state["v"][layer][safe]
-    ks = (state["k_scale"][layer][safe]
-          if "k_scale" in state else None)
-    vs = (state["v_scale"][layer][safe]
-          if "v_scale" in state else None)
+
+    def heads(key, hd):             # (B, MB, bs, K*hd) -> (B, MB, bs, K, hd)
+        if key not in state:
+            return None
+        x = state[key][layer, safe]
+        return x.reshape(x.shape[:3] + (n_kv_heads, hd))
+
+    hd = state["k"].shape[-1] // n_kv_heads
+    kq, vq = heads("k", hd), heads("v", hd)
+    ks, vs = heads("k_scale", 1), heads("v_scale", 1)
     k = quant_decode(kq, ks, dtype)
     v = quant_decode(vq, vs, dtype)
     mask = in_range[:, :, None, None, None]
@@ -462,13 +481,14 @@ def gather(state: Dict[str, jax.Array], layer: int, block_table: jax.Array,
 
 
 class PagedKVCache:
-    """Device storage: (L, n_blocks, block, K, hd) per k/v (+ int8 scales).
+    """Device storage: (L, n_blocks, block, K*hd) per k/v (+ int8 scales
+    (L, n_blocks, block, K)); see the module docstring for the layout.
     Thin stateful wrapper over the pure functions above: every method
     rebinds ``self.state`` to the functionally-updated pytree.
 
     ``sharding`` (see :func:`init_state`) lays the pool out over a mesh —
-    the model-parallel engine splits the KV-head axis so every shard holds
-    its heads of every page."""
+    the model-parallel engine splits the folded head axis so every shard
+    holds its whole heads of every page."""
 
     def __init__(self, cfg: PagedKVConfig, dtype=jnp.bfloat16,
                  sharding=None):
@@ -517,7 +537,8 @@ class PagedKVCache:
 
     def gather(self, layer: int, block_table: jax.Array,
                dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
-        return gather(self.state, layer, block_table, dtype)
+        return gather(self.state, layer, block_table,
+                      self.cfg.n_kv_heads, dtype)
 
     def hbm_bytes(self) -> int:
         n = self.k.size * self.k.dtype.itemsize * 2

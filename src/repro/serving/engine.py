@@ -76,10 +76,10 @@ verify-round depth histogram.
 launch/serve.py ``--model-parallel``). One serving ShardCtx
 (parallel/sharding.make_serving_ctx) drives every placement: parameters
 partition through the same ``state_shardings`` resolver training uses,
-the paged KV pool splits its KV-head axis (``kv_pool`` spec — each shard
-owns K/tp heads of every page, so appends, truncation and the null-write
-sentinel stay shard-local), and the SSM pools split their conv-channel /
-SSD-head axes. All three jit steps then compute *per-shard* paged
+the paged KV pool splits its folded KV-head axis on head boundaries
+(``kv_pool`` spec — each shard owns K/tp whole heads of every page, so
+appends, truncation and the null-write sentinel stay shard-local), and
+the SSM pools split their conv-channel / SSD-head axes. All three jit steps then compute *per-shard* paged
 attention partials — head-sharded (o, m, l) merge shard-locally via
 merge_partials, never a collective — and GSPMD materializes the
 model-axis psum/all-gather at the row-parallel seams (wo, MLP down-proj,
@@ -239,8 +239,9 @@ class Engine:
         # model-axis sharding: one ShardCtx drives every placement — params
         # through the training-side state_shardings resolver, activations
         # through the blocks' constrain() calls, the paged KV pool through
-        # the "kv_pool" spec (KV-head axis split). The scheduler and block
-        # accounting stay host-global: policy is device-count-agnostic.
+        # the "kv_pool" spec (folded KV-head axis split on head bounds). The
+        # scheduler and block accounting stay host-global: policy is
+        # device-count-agnostic.
         self.mesh = mesh
         self._ctx = make_serving_ctx(cfg, mesh) if mesh is not None else None
         self.tp_degree = int(mesh.shape["model"]) if mesh is not None else 1
@@ -271,7 +272,7 @@ class Engine:
         kv_sharding = None
         if self._ctx is not None:
             pool_shape = (self.kv_cfg.n_layers, n_blocks, block_size,
-                          self.kv_cfg.n_kv_heads, self.kv_cfg.head_dim)
+                          self.kv_cfg.n_kv_heads * self.kv_cfg.head_dim)
             kv_sharding = NamedSharding(
                 mesh, self._ctx.spec_for("kv_pool", pool_shape))
         self.kv = PagedKVCache(self.kv_cfg, sharding=kv_sharding)
@@ -710,7 +711,13 @@ class Engine:
     # stack; each caller parameterizes only
     #   * the attention read path (``attn_read``): paged multi-query
     #     prefix partial + fresh-window causal partial + LSE merge for
-    #     all three (fused decode is the T=1 window), and
+    #     all three (fused decode is the T=1 window). The read reaches the
+    #     paged pool in place: the step closes over the whole stacked
+    #     (L, n_blocks, block, K*hd) pool as a loop invariant, and the body
+    #     hands ``attn_read`` the flat attention-layer index
+    #     ``period * attn_per_period + r``, which the paged read takes as
+    #     its layer coordinate. No step slices the pool per layer or moves
+    #     it; the post-scan scatter writes the donated pool in place, and
     #   * the SSM cache plumbing (``ssm_step``): T=1 decode with an
     #     active-slot mask, T>1 chunk-continue, or the per-token verify
     #     scan that emits every intermediate state for exact rollback.
@@ -724,9 +731,10 @@ class Engine:
     def _make_stack_body(self, *, positions, attn_read, ssm_step):
         cfg, model, ctx = self.cfg, self.model, self._ctx
         quant = self.kv_cfg.kv_quant
+        n_attn_pp = len(self._attn_pos)
 
         def body(x, xs):
-            lp, kv_slice, ssm_slice, inj = xs
+            lp, period, ssm_slice, inj = xs
             # fault injection: poison selected rows' hidden state entering
             # this layer period with NaN (inj is (B,) bool, all-False in
             # normal operation — a traced select, never a retrace). The
@@ -754,8 +762,8 @@ class Engine:
                     q, k, v = jax.lax.optimization_barrier((q, k, v))
                     kq, ks = C.quant_encode(k, quant)
                     vq, vs = C.quant_encode(v, quant)
-                    out = attn_read(q, (kq, ks, vq, vs), k.dtype,
-                                    kv_slice, r)
+                    out = attn_read(q, (kq, ks, vq, vs),
+                                    period * n_attn_pp + r)
                     # head-sharded attention produces shard-complete heads
                     # (partials LSE-merge locally); the row-parallel wo
                     # contraction is where the model-axis psum materializes
@@ -781,14 +789,12 @@ class Engine:
 
         return body
 
-    def _kv_xs(self, kv_state):
-        """(L, ...) storage -> (n_periods, attn-per-period, ...) scan xs."""
-        n_attn_pp = len(self._attn_pos)
-        if not n_attn_pp:
-            return {}
-        return {kk: vv.reshape((self.model.n_periods, n_attn_pp)
-                               + vv.shape[1:])
-                for kk, vv in kv_state.items()}
+    def _scan_stack(self, body, params, x, ssm_xs, inj):
+        """Run ``body`` over the layer stack; each iteration gets its
+        period's weights, its period index (for the layer coordinate of
+        the paged reads), its SSM slices and its injection row."""
+        periods = jnp.arange(self.model.n_periods, dtype=jnp.int32)
+        return jax.lax.scan(body, x, (params["blocks"], periods, ssm_xs, inj))
 
     def _collect_enc(self, kv_ys):
         """Scan ys (n_periods, R, B, T, ...) -> storage-ready
@@ -820,26 +826,22 @@ class Engine:
         self.trace_counts[("chunk", cn, mbb)] += 1
         cfg, model = self.cfg, self.model
         bs = self.block_size
-        quant = self.kv_cfg.kv_quant
         n_attn_pp = len(self._attn_pos)
         sm_scale = 1.0 / float(np.sqrt(max(cfg.head_dim, 1)))
 
         x = model._embed_in(params, tokens)                  # (1, C, d)
         positions = ctx + jnp.arange(cn, dtype=jnp.int32)[None, :]
-        kv_xs = self._kv_xs(kv_state)
         ssm_xs = jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
             ssm_states)
         table0 = table[0]
 
-        def attn_read(q, enc, kdtype, kv_slice, r):
+        def attn_read(q, enc, layer):
             kq, ks, vq, vs = enc
             o_c, m_c, l_c = fd.paged_flash_prefix_partial(
-                q, kv_slice["k"][r], kv_slice["v"][r], table, ctx[None],
-                k_scale=(kv_slice["k_scale"][r]
-                         if quant == "int8" else None),
-                v_scale=(kv_slice["v_scale"][r]
-                         if quant == "int8" else None),
+                q, kv_state["k"], kv_state["v"], layer, table, ctx[None],
+                k_scale=kv_state.get("k_scale"),
+                v_scale=kv_state.get("v_scale"),
                 impl=self._paged_impl, sm_scale=sm_scale)
             # attend to the fresh chunk as the cache will store it (int8
             # roundtrip under kv_quant), causal within the chunk
@@ -856,8 +858,7 @@ class Engine:
 
         body = self._make_stack_body(positions=positions,
                                      attn_read=attn_read, ssm_step=ssm_step)
-        x, (kv_ys, new_ssm) = jax.lax.scan(
-            body, x, (params["blocks"], kv_xs, ssm_xs, inj))
+        x, (kv_ys, new_ssm) = self._scan_stack(body, params, x, ssm_xs, inj)
 
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         logits = model._head(params, last)[:, 0]
@@ -950,24 +951,19 @@ class Engine:
         self.trace_counts[("decode", 1, int(table.shape[1]))] += 1
         cfg, model = self.cfg, self.model
         bs = self.block_size
-        quant = self.kv_cfg.kv_quant
         n_attn_pp = len(self._attn_pos)
         sm_scale = 1.0 / float(np.sqrt(max(cfg.head_dim, 1)))
 
         x = model._embed_in(params, tokens[:, None])
         positions = lengths[:, None]
-        kv_xs = self._kv_xs(kv_state)
         ssm_xs = ssm_states
 
-        def attn_read(q, enc, kdtype, kv_slice, r):
+        def attn_read(q, enc, layer):
             kq, ks, vq, vs = enc
             o_c, m_c, l_c = fd.paged_flash_decode_partial(
-                q[:, 0], kv_slice["k"][r], kv_slice["v"][r], table,
-                lengths,
-                k_scale=(kv_slice["k_scale"][r]
-                         if quant == "int8" else None),
-                v_scale=(kv_slice["v_scale"][r]
-                         if quant == "int8" else None),
+                q[:, 0], kv_state["k"], kv_state["v"], layer, table,
+                lengths, k_scale=kv_state.get("k_scale"),
+                v_scale=kv_state.get("v_scale"),
                 impl=self._paged_impl, sm_scale=sm_scale)
             # the fresh token attends to itself via a single-position
             # causal partial, LSE-merged with the cache — its KV lands in
@@ -1000,8 +996,7 @@ class Engine:
 
         body = self._make_stack_body(positions=positions,
                                      attn_read=attn_read, ssm_step=ssm_step)
-        x, (kv_ys, new_ssm) = jax.lax.scan(
-            body, x, (params["blocks"], kv_xs, ssm_xs, inj))
+        x, (kv_ys, new_ssm) = self._scan_stack(body, params, x, ssm_xs, inj)
 
         logits = model._head(params, x)[:, 0]
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1086,27 +1081,23 @@ class Engine:
         self.trace_counts[("verify", cn, mbb)] += 1
         cfg, model = self.cfg, self.model
         bs = self.block_size
-        quant = self.kv_cfg.kv_quant
         n_attn_pp = len(self._attn_pos)
         bsz = tokens.shape[0]
         sm_scale = 1.0 / float(np.sqrt(max(cfg.head_dim, 1)))
 
         x = model._embed_in(params, tokens)                  # (B, T, d)
         positions = ctx[:, None] + jnp.arange(cn, dtype=jnp.int32)[None, :]
-        kv_xs = self._kv_xs(kv_state)
         ssm_xs = ssm_states
         # per-row validity: [last token, proposals...] then padding; an
         # inactive slot has n_valid == 0 (whole row inert)
         valid_rows = jnp.arange(cn)[None, :] < n_valid[:, None]
 
-        def attn_read(q, enc, kdtype, kv_slice, r):
+        def attn_read(q, enc, layer):
             kq, ks, vq, vs = enc
             o_c, m_c, l_c = fd.paged_flash_prefix_partial(
-                q, kv_slice["k"][r], kv_slice["v"][r], table, ctx,
-                k_scale=(kv_slice["k_scale"][r]
-                         if quant == "int8" else None),
-                v_scale=(kv_slice["v_scale"][r]
-                         if quant == "int8" else None),
+                q, kv_state["k"], kv_state["v"], layer, table, ctx,
+                k_scale=kv_state.get("k_scale"),
+                v_scale=kv_state.get("v_scale"),
                 impl=self._paged_impl, sm_scale=sm_scale)
             ka = C.quant_decode(kq, ks, jnp.float32)
             va = C.quant_decode(vq, vs, jnp.float32)
@@ -1121,8 +1112,7 @@ class Engine:
 
         body = self._make_stack_body(positions=positions,
                                      attn_read=attn_read, ssm_step=ssm_step)
-        x, (kv_ys, new_ssm) = jax.lax.scan(
-            body, x, (params["blocks"], kv_xs, ssm_xs, inj))
+        x, (kv_ys, new_ssm) = self._scan_stack(body, params, x, ssm_xs, inj)
 
         logits = model._head(params, x)                      # (B, T, V)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1303,16 +1293,18 @@ class Engine:
         mbb = _next_pow2(-(-max_seq_len // self.block_size))
         bsz = self.max_batch
         # the steps donate their state args: hand them throwaway copies so
-        # the live cache buffers survive the discarded warmup calls
+        # the live cache buffers survive the discarded warmup calls. Each
+        # call's output (a whole pool) is dropped as soon as it is ready:
+        # held beside the next call's copy it would make three pools, more
+        # than a device whose pool is fitted to hold two
         if self.mode == "fused" and self.spec is None:
-            out = self._fused_step(
+            jax.block_until_ready(self._fused_step(
                 self.params,
                 jax.tree_util.tree_map(jnp.copy, self.kv.state),
                 jax.tree_util.tree_map(jnp.copy, self._ssm_states),
                 jnp.zeros((bsz,), jnp.int32), jnp.zeros((bsz,), jnp.int32),
                 jnp.zeros((bsz, mbb), jnp.int32), jnp.zeros((bsz,), bool),
-                jnp.zeros((self.model.n_periods, bsz), bool))
-            jax.block_until_ready(out)
+                jnp.zeros((self.model.n_periods, bsz), bool)))
         if self.prefill_chunk is not None:
             cn = self.prefill_chunk
             buckets = {mbb}
@@ -1324,15 +1316,14 @@ class Engine:
                     buckets.add(b)
                     b *= 2
             for cb in sorted(buckets):
-                out = self._chunk_step(
+                jax.block_until_ready(self._chunk_step(
                     self.params,
                     jax.tree_util.tree_map(jnp.copy, self.kv.state),
                     jax.tree_util.tree_map(jnp.copy, self._ssm_states),
                     jnp.zeros((1, cn), jnp.int32),
                     jnp.asarray(0, jnp.int32), jnp.asarray(cn, jnp.int32),
                     jnp.zeros((1, cb), jnp.int32), jnp.asarray(0, jnp.int32),
-                    jnp.zeros((self.model.n_periods, 1), bool))
-                jax.block_until_ready(out)
+                    jnp.zeros((self.model.n_periods, 1), bool)))
         if self.spec is not None:
             # build every (window-bucket, table-bucket) executable the
             # depth policy can demand: pow2 window widths capped at
@@ -1340,7 +1331,7 @@ class Engine:
             widths = sorted({min(_next_pow2(k), self.spec.depth + 1)
                              for k in range(1, self.spec.depth + 2)})
             for t in widths:
-                out = self._verify_step(
+                jax.block_until_ready(self._verify_step(
                     self.params,
                     jax.tree_util.tree_map(jnp.copy, self.kv.state),
                     jax.tree_util.tree_map(jnp.copy, self._ssm_states),
@@ -1349,8 +1340,7 @@ class Engine:
                     jnp.zeros((bsz,), jnp.int32),
                     jnp.zeros((bsz, mbb), jnp.int32),
                     jnp.zeros((bsz,), bool),
-                    jnp.zeros((self.model.n_periods, bsz), bool))
-                jax.block_until_ready(out)
+                    jnp.zeros((self.model.n_periods, bsz), bool)))
 
     # ------------------------------------------------------------------
     # Legacy decode: the paper-baseline per-layer Python hot loop (eager
@@ -1440,16 +1430,15 @@ class Engine:
                                   self.kv_cfg.n_blocks, active)
         kq, ks = C.quant_encode(k[:, 0], quant)
         vq, vs = C.quant_encode(v[:, 0], quant)
-        st = dict(self.kv.state)
-        st["k"] = st["k"].at[attn_layer, blk, off].set(
-            kq.astype(st["k"].dtype), mode="drop")
-        st["v"] = st["v"].at[attn_layer, blk, off].set(
-            vq.astype(st["v"].dtype), mode="drop")
+        enc = {"k": kq, "v": vq}
         if ks is not None:
-            st["k_scale"] = st["k_scale"].at[attn_layer, blk, off].set(
-                ks, mode="drop")
-            st["v_scale"] = st["v_scale"].at[attn_layer, blk, off].set(
-                vs, mode="drop")
+            enc["k_scale"], enc["v_scale"] = ks, vs
+        st = dict(self.kv.state)
+        for key, val in enc.items():
+            # (B, K, hd | 1) -> one folded pool row per batch slot
+            st[key] = st[key].at[attn_layer, blk, off].set(
+                val.reshape(val.shape[0], -1).astype(st[key].dtype),
+                mode="drop")
         self.kv.state = st
         # f32 softmax accumulation: matches the flash-decode kernels' and
         # the fused step's numerics (bf16 p·v rounding would make the two

@@ -134,22 +134,49 @@ def test_merge_partials_matches_full_softmax():
 # --------------------------------------------------------------------------
 
 
-def _paginate(k, v, table, bs, n_blocks):
-    """Scatter dense (B, S, K, hd) into (n_blocks, bs, K, hd) pages."""
+N_LAYERS = 3     # layers of every test pool; reads name one of them
+
+
+def _pool(k, v, table, bs, n_blocks, layer, kv="f32"):
+    """Page dense (B, S, K, hd) k/v through ``table`` into layer ``layer``
+    of a stacked (N_LAYERS, n_blocks, bs, K*hd) pool, stored as the
+    serving cache stores it (f32, bf16, or int8 with (N_LAYERS, n_blocks,
+    bs, K) scales). The other layers hold noise, so a read of the wrong
+    layer shows. Returns (k_pool, v_pool, k_scale, v_scale)."""
+    from repro.serving import cache as C
     b, s, n_kv, d = k.shape
-    mb = table.shape[1]
-    k_pages = np.zeros((n_blocks, bs, n_kv, d), np.float32)
-    v_pages = np.zeros((n_blocks, bs, n_kv, d), np.float32)
-    for bi in range(b):
-        for j in range(mb):
-            k_pages[table[bi, j]] = np.asarray(k[bi, j * bs:(j + 1) * bs])
-            v_pages[table[bi, j]] = np.asarray(v[bi, j * bs:(j + 1) * bs])
-    return jnp.asarray(k_pages), jnp.asarray(v_pages)
+    noise = np.random.default_rng(11)
+    out, scales = [], []
+    for x in (k, v):
+        p = noise.standard_normal((N_LAYERS, n_blocks, bs, n_kv, d))
+        p = p.astype(np.float32)
+        p[layer] = 0.0
+        for bi in range(b):
+            for j in range(table.shape[1]):
+                p[layer, table[bi, j]] = np.asarray(x[bi, j * bs:(j + 1) * bs])
+        p, sc = C.quant_encode(jnp.asarray(p), "int8" if kv == "int8"
+                               else "none")
+        if kv == "bf16":
+            p = p.astype(jnp.bfloat16)
+        out.append(p.reshape(N_LAYERS, n_blocks, bs, n_kv * d))
+        scales.append(None if sc is None
+                      else sc.reshape(N_LAYERS, n_blocks, bs, n_kv))
+    return (*out, *scales)
+
+
+def _layer_view(pool, scale, layer, n_kv):
+    """One layer of a folded pool, dequantized and viewed per head:
+    (n_blocks, bs, K, hd) f32."""
+    from repro.serving import cache as C
+    x = pool[layer].reshape(pool.shape[1:3] + (n_kv, -1))
+    sc = None if scale is None else scale[layer][..., None]
+    return C.quant_decode(x, sc, jnp.float32)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("h,kv", [(4, 4), (4, 2), (4, 1)])
-def test_paged_flash_decode(impl, h, kv):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode(impl, h, kv, layer):
     from repro.kernels.flash_decode import paged_flash_decode
     b, bs, mb, n_blocks, d = 2, 8, 3, 16, 32
     s = bs * mb
@@ -158,18 +185,18 @@ def test_paged_flash_decode(impl, h, kv):
     v = rand(2, (b, s, kv, d), jnp.float32)
     lengths = jnp.asarray([s - 3, bs + 1], jnp.int32)
     table = np.asarray([[5, 2, 9], [1, 12, 0]], np.int32)
-    k_pages, v_pages = _paginate(k, v, table, bs, n_blocks)
-    out = paged_flash_decode(q, k_pages, v_pages, jnp.asarray(table),
+    k_pool, v_pool, _, _ = _pool(k, v, table, bs, n_blocks, layer)
+    out = paged_flash_decode(q, k_pool, v_pool, layer, jnp.asarray(table),
                              lengths, impl=impl, interpret=True)
     want = ref.flash_decode_ref(q[:, None], k, v, lengths)[:, 0]
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_paged_flash_decode_int8(impl):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_flash_decode_int8(impl, layer):
     """Int8 pages dequantize in-kernel via the scale tensors; the result
     stays within int8 roundtrip error of the unquantized answer."""
-    from repro.serving.cache import quant_encode
     from repro.kernels.flash_decode import paged_flash_decode
     b, h, kv, bs, mb, n_blocks, d = 2, 4, 2, 8, 2, 8, 32
     s = bs * mb
@@ -178,10 +205,8 @@ def test_paged_flash_decode_int8(impl):
     v = rand(2, (b, s, kv, d), jnp.float32)
     lengths = jnp.asarray([s, s - 5], jnp.int32)
     table = np.asarray([[3, 1], [6, 4]], np.int32)
-    k_pages, v_pages = _paginate(k, v, table, bs, n_blocks)
-    kq, ks = quant_encode(k_pages, "int8")
-    vq, vs = quant_encode(v_pages, "int8")
-    out = paged_flash_decode(q, kq, vq, jnp.asarray(table), lengths,
+    kq, vq, ks, vs = _pool(k, v, table, bs, n_blocks, layer, kv="int8")
+    out = paged_flash_decode(q, kq, vq, layer, jnp.asarray(table), lengths,
                              k_scale=ks, v_scale=vs, impl=impl,
                              interpret=True)
     want = ref.flash_decode_ref(q[:, None], k, v, lengths)[:, 0]
@@ -218,16 +243,16 @@ def _prefix_oracle(q, k_dense, v_dense, lengths):
 
 
 @pytest.mark.parametrize("t", [1, 4, 8])
-@pytest.mark.parametrize("quant", [False, True])
-@pytest.mark.parametrize("h,kv", [(4, 2), (4, 1)])
-def test_paged_mq_kernel_contract(t, quant, h, kv):
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("h,kv", [(4, 4), (4, 2), (4, 1)])
+def test_paged_mq_kernel_contract(t, kv_dtype, h, kv):
     """The Pallas multi-query kernel and the XLA fallback agree partial-
     for-partial, and their normalized output matches the dense oracle —
     across window widths, a padded table bucket (live columns < bucket),
-    int8 in-kernel dequant, GQA groups, and a zero-length row."""
-    from repro.serving import cache as C
+    bf16 pages, int8 in-kernel dequant, MHA and GQA groups, a zero-length
+    row, and a layer other than 0 of the stacked pool."""
     from repro.kernels import flash_decode as fd
-    b, bs, mb, n_blocks, d = 3, 4, 4, 16, 16
+    b, bs, mb, n_blocks, d, layer = 3, 4, 4, 16, 16, 1
     s = bs * mb
     q = rand(0, (b, t, h, d), jnp.float32)
     k = rand(1, (b, s, kv, d), jnp.float32)
@@ -235,15 +260,12 @@ def test_paged_mq_kernel_contract(t, quant, h, kv):
     # full row / short row (trailing bucket columns dead) / zero-length row
     lengths = jnp.asarray([s, bs + 2, 0], jnp.int32)
     table = np.asarray([[5, 2, 9, 1], [3, 7, 0, 0], [0, 0, 0, 0]], np.int32)
-    k_pages, v_pages = _paginate(k, v, table, bs, n_blocks)
-    ks = vs = None
-    if quant:
-        k_pages, ks = C.quant_encode(k_pages, "int8")
-        v_pages, vs = C.quant_encode(v_pages, "int8")
+    k_pool, v_pool, ks, vs = _pool(k, v, table, bs, n_blocks, layer,
+                                   kv=kv_dtype)
     got = {}
     for impl in ("pallas", "xla"):
         got[impl] = fd.paged_flash_prefix_partial(
-            q, k_pages, v_pages, jnp.asarray(table), lengths,
+            q, k_pool, v_pool, layer, jnp.asarray(table), lengths,
             k_scale=ks, v_scale=vs, impl=impl, interpret=True)
     for a, b_ in zip(got["pallas"], got["xla"]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
@@ -251,9 +273,9 @@ def test_paged_mq_kernel_contract(t, quant, h, kv):
     o, m, l = got["xla"]
     out = np.asarray(o / jnp.maximum(l, 1e-30))
     # the oracle reads the same (dequantized) pages through the table, so
-    # tolerances stay tight even under int8
-    kd = C.quant_decode(k_pages, ks, jnp.float32)[table].reshape(b, s, kv, d)
-    vd = C.quant_decode(v_pages, vs, jnp.float32)[table].reshape(b, s, kv, d)
+    # tolerances stay tight even under bf16 and int8
+    kd = _layer_view(k_pool, ks, layer, kv)[table].reshape(b, s, kv, d)
+    vd = _layer_view(v_pool, vs, layer, kv)[table].reshape(b, s, kv, d)
     want = _prefix_oracle(q, kd, vd, lengths)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
 
@@ -263,19 +285,19 @@ def test_paged_prefix_t1_matches_decode_partial(impl):
     """The multi-query read at T=1 IS the fused decode read: partials are
     bitwise-identical to paged_flash_decode_partial on both impls."""
     from repro.kernels import flash_decode as fd
-    b, h, kv, bs, mb, n_blocks, d = 2, 4, 2, 8, 2, 8, 32
+    b, h, kv, bs, mb, n_blocks, d, layer = 2, 4, 2, 8, 2, 8, 32, 1
     s = bs * mb
     q = rand(0, (b, h, d), jnp.float32)
     k = rand(1, (b, s, kv, d), jnp.float32)
     v = rand(2, (b, s, kv, d), jnp.float32)
     lengths = jnp.asarray([s - 3, bs + 1], jnp.int32)
     table = np.asarray([[3, 1], [6, 4]], np.int32)
-    k_pages, v_pages = _paginate(k, v, table, bs, n_blocks)
+    k_pool, v_pool, _, _ = _pool(k, v, table, bs, n_blocks, layer)
     one = fd.paged_flash_decode_partial(
-        q, k_pages, v_pages, jnp.asarray(table), lengths, impl=impl,
+        q, k_pool, v_pool, layer, jnp.asarray(table), lengths, impl=impl,
         interpret=True)
     mq = fd.paged_flash_prefix_partial(
-        q[:, None], k_pages, v_pages, jnp.asarray(table), lengths,
+        q[:, None], k_pool, v_pool, layer, jnp.asarray(table), lengths,
         impl=impl, interpret=True)
     for a, b_ in zip(one, mq):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_[:, 0]))
@@ -288,7 +310,7 @@ def test_paged_bounded_scan_bitwise(t):
     scan (the skipped columns are provable no-ops), for the single-query
     (t=None) and multi-query paths alike."""
     from repro.kernels import flash_decode as fd
-    b, h, kv, bs, mb, n_blocks, d = 2, 4, 2, 4, 8, 16, 16
+    b, h, kv, bs, mb, n_blocks, d, layer = 2, 4, 2, 4, 8, 16, 16, 2
     s = bs * mb
     qshape = (b, h, d) if t is None else (b, t, h, d)
     q = rand(0, qshape, jnp.float32)
@@ -297,10 +319,10 @@ def test_paged_bounded_scan_bitwise(t):
     # all lengths end far before the last table column (and one row is 0)
     lengths = jnp.asarray([bs + 1, 0], jnp.int32)
     table = np.asarray([list(range(1, 9)), list(range(8, 0, -1))], np.int32)
-    k_pages, v_pages = _paginate(k, v, table, bs, n_blocks)
+    k_pool, v_pool, _, _ = _pool(k, v, table, bs, n_blocks, layer)
     fn = (fd.paged_flash_decode_partial if t is None
           else fd.paged_flash_prefix_partial)
-    outs = {bound: fn(q, k_pages, v_pages, jnp.asarray(table), lengths,
+    outs = {bound: fn(q, k_pool, v_pool, layer, jnp.asarray(table), lengths,
                       impl="xla", bound_scan=bound)
             for bound in (True, False)}
     for a, b_ in zip(outs[True], outs[False]):

@@ -126,8 +126,9 @@ def test_tp_parity_under_preemption():
 
 @needs_devices(4)
 def test_kv_pool_sharded_on_kv_heads():
-    """The paged pool splits its KV-head axis over the model axis (when it
-    divides); scales ride along; the SSM-free layout stays (L,nb,bs,K,hd)."""
+    """The paged pool splits its folded KV-head axis over the model axis
+    (when the degree divides the head count), each shard holding whole
+    heads; the int8 scales ride along. Layout (L, nb, bs, K*hd)."""
     from jax.sharding import PartitionSpec as P
     cfg, params = _setup("qwen1.5-0.5b")
     mesh = make_local_mesh(model=4, data=1)
@@ -136,7 +137,11 @@ def test_kv_pool_sharded_on_kv_heads():
     assert cfg.n_kv_heads % 4 == 0  # smoke config shards 4 kv heads 4-ways
     for key in ("k", "v", "k_scale", "v_scale"):
         spec = eng.kv.state[key].sharding.spec
-        assert tuple(spec) == (None, None, None, "model", None), (key, spec)
+        assert tuple(spec) == (None, None, None, "model"), (key, spec)
+    # each device holds K/4 whole heads of every page
+    hd = cfg.head_dim
+    for shard in eng.kv.state["k"].addressable_shards:
+        assert shard.data.shape[3] == cfg.n_kv_heads // 4 * hd
 
 
 @needs_devices(2)
@@ -168,17 +173,21 @@ def test_tp_indivisible_heads_degrade_to_replication():
     a 3-wide model axis only if available, otherwise replicate check at
     tp=8 is covered by the slow lane). This test pins the *degrade, don't
     crash* contract on the pool spec resolution itself."""
+    import dataclasses
     from repro.parallel.sharding import make_serving_ctx
     cfg, _ = _setup("jamba-v0.1-52b")
     mesh = make_local_mesh(model=2, data=1)
     ctx = make_serving_ctx(cfg, mesh)
-    # kv head axis of the pool: sharded iff divisible
+    # folded kv head axis of the pool: sharded iff the head count divides
     k = max(cfg.n_kv_heads, 1)
-    spec = ctx.spec_for("kv_pool", (2, 8, 8, k, 16))
+    spec = ctx.spec_for("kv_pool", (2, 8, 8, k * 16))
     expected = "model" if k % 2 == 0 else None
     assert spec[3] == expected
-    # a dimension the degree does not divide replicates instead of raising
-    assert ctx.spec_for("kv_pool", (2, 8, 8, 3, 16))[3] is None
+    # a head count the degree does not divide replicates instead of
+    # raising, even where the folded axis (3 heads of 16) would divide
+    odd = make_serving_ctx(dataclasses.replace(cfg, n_kv_heads=3), mesh)
+    assert odd.spec_for("kv_pool", (2, 8, 8, 3 * 16))[3] is None
+    assert odd.spec_for("kv_pool", (2, 8, 8, 3))[3] is None
 
 
 def test_serve_cli_model_parallel_matches_tp1_in_subprocess():

@@ -100,8 +100,12 @@ def test_prefix_and_self_partials_merge_to_full_attention(quant):
     if quant:
         k_pages, ks = C.quant_encode(k_pages, "int8")
         v_pages, vs = C.quant_encode(v_pages, "int8")
+    # the cache's storage: one layer of a (L, nb, bs, K*hd) pool
     o_c, m_c, l_c = fd.paged_flash_prefix_partial(
-        q, k_pages, v_pages, table, lengths, k_scale=ks, v_scale=vs)
+        q, k_pages.reshape(1, nb, bs, n_kv * d),
+        v_pages.reshape(1, nb, bs, n_kv * d), 0, table, lengths,
+        k_scale=None if ks is None else ks.reshape(1, nb, bs, n_kv),
+        v_scale=None if vs is None else vs.reshape(1, nb, bs, n_kv))
     o_n, m_n, l_n = fd.causal_self_partial(q, kf, vf)
     got = fd.merge_partials([(o_c, m_c, l_c), (o_n, m_n, l_n)])
     # dense oracle: gather pages, concat the fresh window at each row's
