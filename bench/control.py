@@ -38,7 +38,8 @@ def readings(prep, conf, mix, lim, seed: int, seconds: float, *,
     if seed != prep.seed:
         for a in jax.tree.leaves(prep.params):
             a.delete()
-        prep.params = eng.params = model.make_weights(conf, seed)
+        prep.params = eng.params = model.make_weights(conf, seed,
+                                                      prep.shardings)
         prep.seed = seed
     plan = traffic.plan(mix, seed, seconds, prep.cfg.vocab_size)
     run = driver.drive(eng, plan, clock(), float(mix["ramp_s"]), seconds,

@@ -3,10 +3,15 @@
 The configuration file (``bench/configs/<name>.json``) holds the model's
 published ``config.json`` keys as they are run, and an ``engine`` section
 (block size, prefill chunk, ``max_batch``, KV dtype, chips); the KV pool
-is always fitted to the device (:func:`fit_pool`). The weights are made
+is always fitted to the chips (:func:`fit_pool`). The weights are made
 here, from the seed, not by the program: :func:`make_weights` draws every
 leaf on the device in one jitted call, in bf16, and checks that the tree
 has the layout the program's model expects.
+
+On more than one chip the engine runs the program's tensor-parallel path:
+a (data 1, model n) mesh over the cell's chips (:func:`make_mesh`), every
+weight drawn straight into the sharding the engine gives it
+(:func:`param_shardings`), so no chip ever holds the whole tree.
 """
 from __future__ import annotations
 
@@ -72,8 +77,9 @@ def padded_vocab(v: int) -> int:
 
 
 def layout(conf) -> Dict:
-    """Every weight as (shape, init): ``ones``, ``embed``, ``bias``, or an
-    int, the fan-in a normal draw is scaled by."""
+    """Every weight as (shape, init): ``ones``, ``embed``, ``head`` (an
+    untied output projection), ``bias``, or an int, the fan-in a normal
+    draw is scaled by."""
     L, d = conf["num_hidden_layers"], conf["hidden_size"]
     h, k, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
                 head_dim(conf))
@@ -86,9 +92,12 @@ def layout(conf) -> Dict:
                    bv=((L, k, hd), "bias"))
     ffn = {"ln": ((L, d), "ones"), "w_gate": ((L, d, f), d),
            "w_up": ((L, d, f), d), "w_down": ((L, f, d), f)}
-    return {"embed": ((padded_vocab(conf["vocab_size"]), d), "embed"),
-            "final_ln": ((d,), "ones"),
-            "blocks": {"pos0": {"mix": mix, "ffn": ffn}}}
+    out = {"embed": ((padded_vocab(conf["vocab_size"]), d), "embed"),
+           "final_ln": ((d,), "ones"),
+           "blocks": {"pos0": {"mix": mix, "ffn": ffn}}}
+    if not conf["tie_word_embeddings"]:
+        out["head"] = ((d, padded_vocab(conf["vocab_size"])), "head")
+    return out
 
 
 def _leaves(tree, prefix=()):
@@ -107,8 +116,10 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(int(word))
 
 
-def make_weights(conf, seed: int):
-    """All weights, bf16, on the default device, from one jitted call."""
+def make_weights(conf, seed: int, shardings=None):
+    """All weights, bf16, from one jitted call: on the default device, or
+    each leaf straight into its sharding in ``shardings`` (a tree like the
+    weights'). The draw is the same whatever the sharding."""
     import jax
     import jax.numpy as jnp
 
@@ -126,6 +137,9 @@ def make_weights(conf, seed: int):
             elif init == "embed":
                 a = jax.random.normal(k, shp, jnp.float32) / math.sqrt(shp[1])
                 a = jnp.where(jnp.arange(shp[0])[:, None] < vocab, a, 0.0)
+            elif init == "head":
+                a = jax.random.normal(k, shp, jnp.float32) / math.sqrt(shp[0])
+                a = jnp.where(jnp.arange(shp[1])[None, :] < vocab, a, 0.0)
             else:
                 a = jax.random.normal(k, shp, jnp.float32) / math.sqrt(init)
             node = out
@@ -134,7 +148,40 @@ def make_weights(conf, seed: int):
             node[path[-1]] = a.astype(jnp.bfloat16)
         return out
 
-    return jax.jit(draw)(seed_key(seed))
+    return jax.jit(draw, out_shardings=shardings)(seed_key(seed))
+
+
+def make_mesh(devices):
+    """The serving mesh over the cell's own chips: the axes of the
+    program's ``launch.mesh.make_local_mesh(model=n, data=1)``."""
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh((1, len(devices)), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+
+
+def param_shardings(cfg, mesh):
+    """The sharding the engine gives each weight under ``mesh``, from the
+    program's own resolver (``Engine.__init__`` places its parameters
+    with the same call)."""
+    from repro.models.lm import LM
+    from repro.parallel.sharding import logical_by_path_of, \
+        make_serving_ctx, state_shardings
+    ctx = make_serving_ctx(cfg, mesh)
+    lm = LM(cfg, ctx=ctx)
+    return state_shardings(ctx, lm.abstract_params(),
+                           logical_by_path_of(lm.param_specs()),
+                           component="params")
+
+
+def bytes_per_chip(tree) -> Dict:
+    """Bytes of ``tree``'s arrays that each device holds."""
+    import jax
+    out: Dict = {}
+    for a in jax.tree.leaves(tree):
+        for sh in a.addressable_shards:
+            out[sh.device] = out.get(sh.device, 0) + sh.data.nbytes
+    return out
 
 
 def check_layout(cfg, params) -> None:
@@ -150,12 +197,27 @@ def check_layout(cfg, params) -> None:
                          f"{want} != {got}")
 
 
-def build_engine(cfg, params, es, n_blocks: int):
+def build_engine(cfg, params, es, n_blocks: int, mesh=None):
+    """The program's ``Engine`` over ``params``, on ``mesh`` where the cell
+    has more than one chip.
+
+    Under a mesh the engine makes its pool whole on the default device and
+    only then spreads it over the mesh (``serving/cache.init_state``): a
+    pool fitted to four chips would not fit on one beside its weights. So
+    the engine is built with the host as the default device, and the pool
+    goes from there straight to its shards."""
+    import contextlib
+
+    import jax
     from repro.serving.engine import Engine
-    return Engine(cfg, params, max_batch=es["max_batch"], n_blocks=n_blocks,
-                  block_size=es["block_size"],
-                  kv_quant="int8" if es["kv_dtype"] == "int8" else "none",
-                  prefill_chunk=es["prefill_chunk"])
+
+    place = contextlib.nullcontext() if mesh is None else \
+        jax.default_device(jax.devices("cpu")[0])
+    with place:
+        return Engine(cfg, params, max_batch=es["max_batch"],
+                      n_blocks=n_blocks, block_size=es["block_size"],
+                      kv_quant="int8" if es["kv_dtype"] == "int8" else "none",
+                      prefill_chunk=es["prefill_chunk"], mesh=mesh)
 
 
 def buckets(max_tokens: int, block: int) -> List[int]:
@@ -166,15 +228,19 @@ def buckets(max_tokens: int, block: int) -> List[int]:
 
 
 def _lower(eng, kind: str, n_blocks: int, width: int, sharding):
+    """Compile ``eng``'s decode or chunk step over a pool of ``n_blocks``
+    blocks: the weights and the pool as the engine places them, every
+    other argument by ``sharding``. Nothing is allocated."""
     import jax
     import jax.numpy as jnp
 
-    def sds(shape_, dtype):
-        return jax.ShapeDtypeStruct(shape_, dtype, sharding=sharding)
+    def sds(shape_, dtype, sh=sharding):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=sh)
 
-    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), eng.params)
-    kv = {k: sds((a.shape[0], n_blocks) + tuple(a.shape[2:]), a.dtype)
-          for k, a in eng.kv.state.items()}
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype, a.sharding),
+                          eng.params)
+    kv = {k: sds((a.shape[0], n_blocks) + tuple(a.shape[2:]), a.dtype,
+                 a.sharding) for k, a in eng.kv.state.items()}
     i32, per = jnp.int32, eng.model.n_periods
     if kind == "decode":
         b = eng.max_batch
@@ -196,21 +262,30 @@ def plan_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def fit_pool(cfg, params, es, width: int, device, log: Callable) -> Tuple[int, dict]:
+def fit_pool(cfg, params, es, width: int, devices, log: Callable,
+             mesh=None) -> Tuple[int, dict]:
     """The most blocks whose decode and chunk steps at the widest table,
-    beside the live pool, fit :data:`POOL_SHARE` of the device's memory.
+    beside the live pool, fit :data:`POOL_SHARE` of each chip's memory.
 
-    The steps' plans grow linearly with the pool, so two small probes give
-    the line; the pool found is compiled once more to confirm it. Every
-    program here is the same in every run, so the persistent cache holds
-    them after the first."""
-    from jax.sharding import SingleDeviceSharding
+    The chip with the least memory sets the budget. The steps are compiled
+    with the weights and the pool as the engine places them, so their plans
+    (``memory_analysis``) and a block's bytes are what one chip holds. The
+    plans grow linearly with the pool, so two small probes give the line;
+    the pool found is compiled once more to confirm it. Every program here
+    is the same in every run, so the persistent cache holds them after the
+    first."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec, \
+        SingleDeviceSharding
 
-    limit = device.memory_stats()["bytes_limit"]
+    limit = min(d.memory_stats()["bytes_limit"] for d in devices)
     budget = POOL_SHARE * limit
-    probe = build_engine(cfg, params, es, 2 * es["max_batch"])
-    per_block = probe.kv.hbm_bytes() // probe.kv_cfg.n_blocks
-    shard = SingleDeviceSharding(device)
+    probe = build_engine(cfg, params, es, 2 * es["max_batch"], mesh)
+    per_block = sum(math.prod(a.sharding.shard_shape(a.shape))
+                    * a.dtype.itemsize for a in jax.tree.leaves(
+                        probe.kv.state)) // probe.kv_cfg.n_blocks
+    shard = SingleDeviceSharding(devices[0]) if mesh is None else \
+        NamedSharding(mesh, PartitionSpec())
 
     def need(n):
         plans = {k: plan_bytes(_lower(probe, k, n, width, shard))
@@ -231,7 +306,7 @@ def fit_pool(cfg, params, es, width: int, device, log: Callable) -> Tuple[int, d
         for x in probe.kv.state.values():
             x.delete()
     if n <= 0:
-        raise RuntimeError(f"no pool fits {device}")
+        raise RuntimeError(f"no pool fits {devices}")
     info = {"blocks": n, "pool_bytes": per_block * n,
             "decode_plan_bytes": plans["decode"],
             "chunk_plan_bytes": plans["chunk"], "budget_bytes": int(budget),

@@ -5,10 +5,13 @@ of the program.
 Pre-norm blocks: RMSNorm, q/k/v projections (with biases where the config
 has them), rotary embedding (rotate-half, all of head_dim), causal
 grouped-query attention at 1/sqrt(head_dim), output projection; RMSNorm,
-SwiGLU MLP. Final RMSNorm and the tied embedding as the head, over the
-real vocabulary. Every matmul is float32 at ``Precision.HIGHEST``; the
-bf16 weights are widened one layer at a time, and attention and the head
-run in blocks of rows, so a long sequence fits beside the weights.
+SwiGLU MLP. Final RMSNorm and the head, over the real vocabulary: the
+untied output projection where the weights have one (``head``, (d,
+vocab)), else the tied embedding. Every matmul is float32 at
+``Precision.HIGHEST``; the bf16 weights are widened one layer at a time,
+and attention and the head run in blocks of rows, so a long sequence fits
+beside the weights. Weights sharded over several chips stay where they
+are: the forward runs on those chips.
 
 :func:`make` returns a jitted function of (weights, tokens) that gives, at
 each position, the largest reference logit, the row's standard deviation,
@@ -115,7 +118,10 @@ def make(conf, control: bool = False):
     vocab = int(conf["vocab_size"])
 
     def f(w, tokens):
-        emb = w["embed"][:vocab].astype(jnp.float32)
+        if "head" in w:
+            out_w, eq = w["head"][:, :vocab].astype(jnp.float32), "rd,dv->rv"
+        else:
+            out_w, eq = w["embed"][:vocab].astype(jnp.float32), "rd,vd->rv"
         h = forward_hidden(conf, w, tokens)
         nxt = jnp.concatenate([tokens[1:], tokens[:1]])
         hl = forward_hidden(conf, w, tokens, fp8) if control else h
@@ -123,11 +129,11 @@ def make(conf, control: bool = False):
 
         def block(args):
             hb, hlb, nb = args
-            lg = jnp.einsum("rd,vd->rv", hb, emb, precision=HI)
+            lg = jnp.einsum(eq, hb, out_w, precision=HI)
             out = {"max": lg.max(-1), "sd": lg.std(-1),
                    "next": jnp.take_along_axis(lg, nb[:, None], 1)[:, 0]}
             if control:
-                lo = _mm("rd,vd->rv", hlb, emb, fp8)
+                lo = _mm(eq, hlb, out_w, fp8)
                 pick = jnp.argmax(lo, -1)
                 out["pick"] = jnp.take_along_axis(lg, pick[:, None], 1)[:, 0]
             return out

@@ -48,7 +48,11 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 CACHE_DIR = REPO / ".jax_cache"
-TRACE_S = 4.0           # the profiler covers at most the window's last seconds
+# the profiler covers the window's last TRACE_S seconds, or TRACE_S_SHARDED
+# where the cell runs on several chips: there it records every chip's ops,
+# and the sharded steps run some 60k ops a step a chip (the XLA paged read),
+# so on four v5e chips 1 s of trace took 95 s to stop (224 MB)
+TRACE_S, TRACE_S_SHARDED = 4.0, 0.5
 KERNEL = "tpu_custom_call"  # the paged read: the one Pallas kernel of the step
 
 
@@ -70,13 +74,19 @@ def parse(argv=None) -> argparse.Namespace:
 
 
 def load_cell(name: str, root: Path = REPO):
-    """(benchmark, workload, configuration, traffic mix) of a cell."""
+    """(benchmark, workload, configuration, traffic mix) of a cell. A
+    configuration built for another number of chips than the cell asks
+    for is an error."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     wl = next((w for w in bench["workloads"] if w["name"] == name), None)
     if wl is None:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cfg = next(c for c in bench["configs"] if c["name"] == wl["config"])
     conf = json.loads((root / cfg["file"]).read_text())
+    if int(conf["engine"]["chips"]) != int(wl["chips"]):
+        raise ValueError(f"{name}: configuration {cfg['name']} is built for "
+                         f"{conf['engine']['chips']} chips, the cell asks "
+                         f"for {wl['chips']}")
     mix = json.loads((root / "bench" / "traffic" /
                       f"{wl['traffic']}.json").read_text())
     return bench, wl, conf, mix
@@ -93,7 +103,13 @@ def compile_cache() -> str:
 
 
 def chips(n: int):
+    """The first ``n`` chips, or ``NoChip``. A cell on several chips builds
+    its engine with the host as the default device (bench/model.py
+    build_engine), so there the host's backend is kept on beside them."""
     import jax
+    platforms = jax.config.jax_platforms
+    if n > 1 and platforms and "cpu" not in platforms.split(","):
+        jax.config.update("jax_platforms", platforms + ",cpu")
     devices = jax.devices()
     if devices[0].platform != "tpu":
         raise NoChip(f"needs a TPU, JAX found {devices[0].platform}")
@@ -149,32 +165,41 @@ class Prepared:
     n_blocks: int
     widths: list
     seed: int
+    shardings: object = None    # of the weights, on more than one chip
 
 
 def prepare(conf, mix, seed: int, devices, *, clock=time.monotonic,
             out=log) -> Prepared:
     """Weights from the seed, the fitted pool, and an engine with every
-    table width the cell's lengths can reach compiled."""
+    table width the cell's lengths can reach compiled. On more than one
+    chip the engine is the program's tensor-parallel one, on a mesh over
+    ``devices``, and each weight is drawn into the sharding it serves in."""
     import jax
 
     from bench import model, traffic
 
     es = conf["engine"]
     cfg = model.arch_config(conf)
-    params = model.make_weights(conf, seed)
+    mesh = model.make_mesh(devices) if len(devices) > 1 else None
+    shardings = None if mesh is None else model.param_shardings(cfg, mesh)
+    params = model.make_weights(conf, seed, shardings)
     model.check_layout(cfg, params)
     jax.block_until_ready(params)
+    held = model.bytes_per_chip(params)
+    out(f"weights: {sum(a.nbytes for a in jax.tree.leaves(params))} bytes, "
+        f"per chip {sorted(held.values(), reverse=True)}")
     lo = -(-int(mix["prompt_len"]["min"]) // es["block_size"])
     widths = [w for w in model.buckets(traffic.max_context(mix),
                                        es["block_size"])
               if w >= 1 << (lo - 1).bit_length()]
-    n_blocks, _ = model.fit_pool(cfg, params, es, widths[-1], devices[0], out)
-    eng = model.build_engine(cfg, params, es, n_blocks)
+    n_blocks, _ = model.fit_pool(cfg, params, es, widths[-1], devices, out,
+                                 mesh)
+    eng = model.build_engine(cfg, params, es, n_blocks, mesh)
     t = clock()
     model.warm(eng, widths)
     out(f"warm-up: widths {widths}, {clock() - t:.1f} s, "
         f"trace_counts {dict(eng.trace_counts)}")
-    return Prepared(eng, params, es, cfg, n_blocks, widths, seed)
+    return Prepared(eng, params, es, cfg, n_blocks, widths, seed, shardings)
 
 
 def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
@@ -197,7 +222,12 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
     if trace:
         def start_fn():
             jax.profiler.start_trace(tdir)
-        tr_arg = (min(TRACE_S, seconds), start_fn, jax.profiler.stop_trace)
+        def stop_fn():
+            t = clock()
+            jax.profiler.stop_trace()
+            out(f"trace: stopped in {clock() - t:.1f} s")
+        traced_s = TRACE_S if len(devices) == 1 else TRACE_S_SHARDED
+        tr_arg = (min(traced_s, seconds), start_fn, stop_fn)
 
     start = clock()
     run = driver.drive(eng, plan, start, float(mix["ramp_s"]), seconds,
@@ -211,8 +241,13 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
 
     reduced = None
     if trace:
-        reduced = trace_reduce.read(trace_reduce.find(tdir), KERNEL)
+        t = clock()
+        path = trace_reduce.find(tdir)
+        size = os.path.getsize(path)
+        reduced = trace_reduce.read(path, KERNEL)
         shutil.rmtree(tdir, ignore_errors=True)
+        out(f"trace: {size} bytes, {len(reduced['ops'])} op names, read in "
+            f"{clock() - t:.1f} s")
 
     due = [tr for tr in run.tracked if run.t0 <= tr.due < run.t1]
     failed = sum(tr.state in ("failed", "rejected", "timed_out", "cancelled")
@@ -270,13 +305,20 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
             "idle_gaps": [[k, v] for k, v in reduced["gaps"][:10]]}
         out(f"idle by host span: {reduced['idle_by_span']}")
         out(f"kernel: {reduced['kernel_calls']} events, {reduced['kernel_s']} s")
+        out(f"collectives: {reduced['collective_s']} s a chip; " + str({
+            k[:48]: v for k, v in reduced["ops"].items()
+            if trace_reduce.COLLECTIVE.match(k)}))
     result["checks"] = verdict["checks"]
     return result
 
 
 def main(argv=None) -> int:
     args = parse(argv)
-    bench, wl, conf, mix = load_cell(args.workload)
+    try:
+        bench, wl, conf, mix = load_cell(args.workload)
+    except ValueError as e:
+        log(f"bench/run.py: {e}")
+        return 2
     from bench import correct, peaks as peaks_mod
     lim = correct.limits(wl["name"])
     import jax

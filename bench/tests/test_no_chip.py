@@ -34,3 +34,21 @@ def test_only_the_benchmark_files(tmp_path):
     shutil.copytree(BENCH, tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     assert_no_result(run_in(tmp_path, tmp_path / "cache"))
+
+
+def test_chips_unlike_the_configurations_exit_before_any_weights(tmp_path):
+    """A cell that asks for another number of chips than its configuration
+    is built for is refused as the cell is read: before the look for a
+    chip, and so before any weight is drawn."""
+    import json
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    for wl in bench["workloads"]:
+        if wl["name"] == ARGS[2]:
+            wl["chips"] = 4
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    p = run_in(tmp_path, tmp_path / "cache")
+    assert_no_result(p)
+    assert "built for 1 chips, the cell asks for 4" in p.stderr
+    assert "needs a TPU" not in p.stderr

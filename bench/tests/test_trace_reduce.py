@@ -65,3 +65,32 @@ def test_union_and_gaps():
 def test_no_trace_file(tmp_path):
     with pytest.raises(FileNotFoundError):
         trace_reduce.find(str(tmp_path))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_merge_matches_union(seed):
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    a = rng.uniform(0, 100, 300).round(1)
+    b = a + rng.uniform(0, 3, 300).round(1)
+    s, e = trace_reduce.merge(a, b)
+    assert list(zip(s.tolist(), e.tolist())) == \
+        trace_reduce.union(zip(a.tolist(), b.tolist()))
+
+
+@pytest.mark.parametrize("overlapping", [False, True])
+def test_name_gaps_matches_name_gap(overlapping):
+    import numpy as np
+    rng = np.random.default_rng(int(overlapping))
+    t, spans = 5.0, []
+    while t < 95:
+        d = float(rng.uniform(0.5, 4))
+        spans.append((t, t + d + (2.0 if overlapping else 0.0),
+                      f"bench.{rng.integers(3)}"))
+        t += d + float(rng.choice([0.0, 0.0, 0.7]))
+    spans.sort()
+    ga = np.sort(rng.uniform(0, 100, 400))
+    gb = ga + rng.uniform(0.01, 6, 400)
+    starts = [s[0] for s in spans]
+    assert trace_reduce.name_gaps(ga, gb, spans) == \
+        [trace_reduce.name_gap(g, spans, starts) for g in zip(ga, gb)]
