@@ -1,7 +1,7 @@
-"""The control of the comparison that decides ``correct``: the reference
-computed one precision below the configuration's bf16, float8 e4m3 (every
-matmul operand rounded, activations per row, weights per tensor; see
-bench/reference.py), put in the program's place. At each position of the
+"""The control of the comparison that decides ``correct``: the family's
+reference computed one precision below the configuration's bf16, float8
+e4m3 (every matmul operand rounded, activations per row, weights per
+tensor; see bench/reference.py), put in the program's place. At each position of the
 same prompts and served tokens it reads the reference gap of the token the
 e4m3 forward puts first. The benchmark's own runs do not run it.
 
@@ -10,7 +10,8 @@ e4m3 forward puts first. The benchmark's own runs do not run it.
 
 One process and one engine serve every seed at the cell's own load and
 sizes (the weights are swapped for each seed's, the same shapes, so no
-step compiles again); each prints one JSON line with the program's widest
+step compiles again, and the engine's device state is freed while the
+references run and made anew after); each prints one JSON line with the program's widest
 gap and the control's over the same sampled requests, and the verdict of
 the cell's own comparison (bench/correct.py, with the cell's limits) on
 each: the program's must be correct, the control's not.
@@ -26,7 +27,7 @@ from pathlib import Path
 if __package__ in (None, ""):    # run as a script: python bench/<name>.py
     sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bench import run as R  # noqa: E402  (also puts src/ on the path)
-from bench import correct, driver, model, reference, traffic  # noqa: E402
+from bench import correct, driver, families, model, traffic  # noqa: E402
 
 
 def readings(prep, conf, mix, lim, seed: int, seconds: float, *,
@@ -46,15 +47,20 @@ def readings(prep, conf, mix, lim, seed: int, seconds: float, *,
                        clock=clock, sleep=sleep, spans=spans)
     for r in eng.live_requests():
         eng.cancel(r.rid)
+    # free the program's state before the reference runs, and renew it for
+    # the next seed after
+    model.free_state(eng)
     picked = correct.sample(run, seed)
     length = correct.padded(traffic.max_context(mix))
-    prog = correct.compare(reference.make(conf), prep.params, picked, length)
-    ctl = reference.make(conf, control=True)
+    family = families.load(conf)
+    prog = correct.compare(family.reference(conf), prep.params, picked, length)
+    ctl = family.reference(conf, control=True)
     worst = 0.0
     for tr in picked:
         g = correct.gaps(ctl, prep.params, tr.req.tokens, tr.req.output,
                          length, key="pick")
         worst = max(worst, float(g.max()))
+    model.renew_state(eng)
     ctl_read = dict(prog, worst_gap_sd=worst)
     return {"seed": seed, "program_worst_gap_sd": prog["worst_gap_sd"],
             "control_worst_gap_sd": worst, "requests": prog["requests"],

@@ -4,9 +4,11 @@ number in the metric's unit, or None when the run holds nothing to read;
 the harness then leaves the metric out of the result.
 
 ``ctx`` (bench/run.py ``Context``) holds the driver's ``run``, the
-cell's engine settings ``es``, the model's ``shape`` (bench/flops.py),
-the chip's ``peaks``, ``chips``, the ``trace`` reduction (or None),
-``compiles`` counted in the window, ``peak_bytes`` and ``n_blocks``.
+cell's engine settings ``es``, the model's ``counts`` (its family's
+``bench.flops.Counts``), the chip's ``peaks``, ``chips``, the ``trace``
+reduction (or None; bench/trace_reduce.py, with each kernel's seconds by
+its name), ``compiles`` counted in the window, ``peak_bytes`` and
+``n_blocks``.
 """
 import importlib
 
@@ -39,12 +41,11 @@ def traced_flops(ctx) -> float:
     window: weights once per token, attention over each token's context,
     the head for decoded tokens and each prompt's last; prefill recomputed
     after a preemption does not count."""
-    from bench.flops import chunk_flops, token_flops
-    total = 0.0
+    c, total = ctx.counts, 0.0
     for s in traced_steps(ctx):
         for length in s.decode_lens:
-            total += token_flops(ctx.shape, length)
+            total += c.token_flops(length)
         start, n, last = s.new_prefill
         if n:
-            total += chunk_flops(ctx.shape, start, n, last)
+            total += c.chunk_flops(start, n, last)
     return total

@@ -1,10 +1,14 @@
 """Kernel: the paged attention read's share of its roofline. The least
-time of every call in the traced window (bench/flops.py: each decoded
-row's live pages, each chunk's paged prefix, all layers; each call bound
-by the larger of its operations over peak FLOP/s and its bytes over peak
-bandwidth), over the summed device time of the kernel's events."""
+time of every call in the traced window (the family's counts,
+bench/flops.py: each decoded row's live pages, each chunk's paged prefix,
+every attention layer; each call bound by the larger of its operations
+over peak FLOP/s and its bytes over peak bandwidth), over the summed
+device time of the paged read's own kernels' events."""
 from bench.flops import roofline_seconds
 from bench.layer_metrics import traced_steps
+
+# the Pallas kernels of the paged read (kernels/flash_decode.py), by name
+KERNELS = ("paged_decode", "paged_prefix")
 
 
 def calls(steps):
@@ -15,11 +19,20 @@ def calls(steps):
             yield s.chunk[0], s.chunk[1]
 
 
+def kernel_seconds(trace) -> float:
+    """Device seconds of the paged read's kernels in a reduced trace."""
+    return sum(trace["kernels"][k]["s"] for k in KERNELS
+               if k in trace["kernels"])
+
+
 def read(ctx):
-    if ctx.trace is None or not ctx.trace["kernel_s"]:
+    if ctx.trace is None:
+        return None
+    sec = kernel_seconds(ctx.trace)
+    if not sec:
         return None
     least, by_ops, by_bytes = roofline_seconds(
-        ctx.shape, calls(traced_steps(ctx)), ctx.peaks["bf16_flops"],
+        ctx.counts, calls(traced_steps(ctx)), ctx.peaks["bf16_flops"],
         ctx.peaks["hbm_bytes_per_s"])
     ctx.notes["paged_attn_bound"] = "memory" if by_bytes >= by_ops else "compute"
-    return 100.0 * least / ctx.trace["kernel_s"]
+    return 100.0 * least / sec

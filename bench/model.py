@@ -1,12 +1,14 @@
 """From a configuration file to a warmed serving engine.
 
 The configuration file (``bench/configs/<name>.json``) holds the model's
-published ``config.json`` keys as they are run, and an ``engine`` section
-(block size, prefill chunk, ``max_batch``, KV dtype, chips); the KV pool
-is always fitted to the chips (:func:`fit_pool`). The weights are made
-here, from the seed, not by the program: :func:`make_weights` draws every
-leaf on the device in one jitted call, in bf16, and checks that the tree
-has the layout the program's model expects.
+published ``config.json`` keys as they are run, an optional ``family``
+(bench/families/, ``dense`` where it is left out) and an ``engine``
+section (block size, prefill chunk, ``max_batch``, KV dtype, chips); the
+KV pool is always fitted to the chips (:func:`fit_pool`). The weights are
+made here, from the seed, not by the program: :func:`make_weights` draws
+every leaf of the family's layout on the device in one jitted call, and
+:func:`check_layout` checks that the tree is the one the program's model
+reads.
 
 On more than one chip the engine runs the program's tensor-parallel path:
 a (data 1, model n) mesh over the cell's chips (:func:`make_mesh`), every
@@ -15,89 +17,20 @@ weight drawn straight into the sharding the engine gives it
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
-from bench.flops import Shape
+from bench import families
 
-# keys of a source config that the serving stack does not model: the run
-# may only hold them at the value that changes nothing
-NEUTRAL = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-           "logits_scaling": 1.0}
 VOCAB_PAD = 512
 POOL_SHARE = 0.8    # of the device's memory, for the pool and the steps' plans
 
 
-def head_dim(conf) -> int:
-    return int(conf.get("head_dim")
-               or conf["hidden_size"] // conf["num_attention_heads"])
-
-
-def arch_config(conf):
-    """The program's ``ArchConfig`` for a dense decoder configuration."""
-    from repro.core.config import ArchConfig
-
-    for key, neutral in NEUTRAL.items():
-        if float(conf.get(key, neutral)) != neutral:
-            raise ValueError(f"{conf['name']}: {key}={conf[key]} is not "
-                             f"modelled by the serving stack")
-    hd = head_dim(conf)
-    if "attention_multiplier" in conf and not math.isclose(
-            conf["attention_multiplier"], hd ** -0.5):
-        raise ValueError(f"{conf['name']}: attention_multiplier "
-                         f"{conf['attention_multiplier']} is not 1/sqrt(hd)")
-    return ArchConfig(
-        name=conf["name"], family="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], head_dim=hd,
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qkv_bias=qkv_bias(conf), rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]))
-
-
-def qkv_bias(conf) -> bool:
-    return bool(conf.get("attention_bias", conf["model_type"] == "qwen2"))
-
-
-def shape(conf, es) -> Shape:
-    return Shape(layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-                 heads=conf["num_attention_heads"],
-                 kv_heads=conf["num_key_value_heads"], head_dim=head_dim(conf),
-                 d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-                 block=es["block_size"],
-                 kv_bytes=1 if es["kv_dtype"] == "int8" else 2)
-
-
 def padded_vocab(v: int) -> int:
     return -(-v // VOCAB_PAD) * VOCAB_PAD
-
-
-def layout(conf) -> Dict:
-    """Every weight as (shape, init): ``ones``, ``embed``, ``head`` (an
-    untied output projection), ``bias``, or an int, the fan-in a normal
-    draw is scaled by."""
-    L, d = conf["num_hidden_layers"], conf["hidden_size"]
-    h, k, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
-                head_dim(conf))
-    f = conf["intermediate_size"]
-    mix = {"ln": ((L, d), "ones"),
-           "wq": ((L, d, h, hd), d), "wk": ((L, d, k, hd), d),
-           "wv": ((L, d, k, hd), d), "wo": ((L, h, hd, d), h * hd)}
-    if qkv_bias(conf):
-        mix.update(bq=((L, h, hd), "bias"), bk=((L, k, hd), "bias"),
-                   bv=((L, k, hd), "bias"))
-    ffn = {"ln": ((L, d), "ones"), "w_gate": ((L, d, f), d),
-           "w_up": ((L, d, f), d), "w_down": ((L, f, d), f)}
-    out = {"embed": ((padded_vocab(conf["vocab_size"]), d), "embed"),
-           "final_ln": ((d,), "ones"),
-           "blocks": {"pos0": {"mix": mix, "ffn": ffn}}}
-    if not conf["tie_word_embeddings"]:
-        out["head"] = ((d, padded_vocab(conf["vocab_size"])), "head")
-    return out
 
 
 def _leaves(tree, prefix=()):
@@ -117,20 +50,31 @@ def seed_key(seed: int):
 
 
 def make_weights(conf, seed: int, shardings=None):
-    """All weights, bf16, from one jitted call: on the default device, or
-    each leaf straight into its sharding in ``shardings`` (a tree like the
-    weights'). The draw is the same whatever the sharding."""
+    """Every weight of the family's layout from one jitted call: on the
+    default device, or each leaf straight into its sharding in
+    ``shardings`` (a tree like the weights'). The draw is the same
+    whatever the sharding.
+
+    Leaf ``i``, in the sorted order of the paths, is drawn from the seed's
+    key folded with ``i``, by its init: ``ones``, ``bias`` (normal x 0.1),
+    ``embed`` (normal over sqrt(d), padded vocabulary rows zero), ``head``
+    (likewise, for an untied (d, vocab) projection), an int (the fan-in a
+    normal draw is scaled by), or a function of (key, shape) that returns
+    float32. It is cast to its dtype, bf16 unless the layout names
+    another."""
     import jax
     import jax.numpy as jnp
 
-    lay = layout(conf)
+    lay = families.load(conf).layout(conf)
     vocab = conf["vocab_size"]
 
     def draw(key):
         out: Dict = {}
-        for i, (path, (shp, init)) in enumerate(_leaves(lay)):
+        for i, (path, (shp, init, *dtype)) in enumerate(_leaves(lay)):
             k = jax.random.fold_in(key, i)
-            if init == "ones":
+            if callable(init):
+                a = init(k, shp)
+            elif init == "ones":
                 a = jnp.ones(shp, jnp.float32)
             elif init == "bias":
                 a = 0.1 * jax.random.normal(k, shp, jnp.float32)
@@ -145,7 +89,7 @@ def make_weights(conf, seed: int, shardings=None):
             node = out
             for p in path[:-1]:
                 node = node.setdefault(p, {})
-            node[path[-1]] = a.astype(jnp.bfloat16)
+            node[path[-1]] = a.astype(dtype[0] if dtype else jnp.bfloat16)
         return out
 
     return jax.jit(draw, out_shardings=shardings)(seed_key(seed))
@@ -197,6 +141,14 @@ def check_layout(cfg, params) -> None:
                          f"{want} != {got}")
 
 
+def _home(mesh):
+    """Where the engine makes its device state: the default device, or
+    under a mesh the host (see :func:`build_engine`)."""
+    import jax
+    return contextlib.nullcontext() if mesh is None else \
+        jax.default_device(jax.devices("cpu")[0])
+
+
 def build_engine(cfg, params, es, n_blocks: int, mesh=None):
     """The program's ``Engine`` over ``params``, on ``mesh`` where the cell
     has more than one chip.
@@ -206,18 +158,42 @@ def build_engine(cfg, params, es, n_blocks: int, mesh=None):
     pool fitted to four chips would not fit on one beside its weights. So
     the engine is built with the host as the default device, and the pool
     goes from there straight to its shards."""
-    import contextlib
-
-    import jax
     from repro.serving.engine import Engine
 
-    place = contextlib.nullcontext() if mesh is None else \
-        jax.default_device(jax.devices("cpu")[0])
-    with place:
+    with _home(mesh):
         return Engine(cfg, params, max_batch=es["max_batch"],
                       n_blocks=n_blocks, block_size=es["block_size"],
                       kv_quant="int8" if es["kv_dtype"] == "int8" else "none",
                       prefill_chunk=es["prefill_chunk"], mesh=mesh)
+
+
+def device_state(eng):
+    """Every device array the engine holds beside its weights: the KV pool
+    and the per-slot recurrent state (``{}`` for a model with none)."""
+    return eng.kv.state, eng._ssm_states
+
+
+def free_state(eng) -> None:
+    """Delete the engine's device state, as before the reference runs."""
+    import jax
+    for a in jax.tree.leaves(device_state(eng)):
+        a.delete()
+
+
+def renew_state(eng) -> None:
+    """Give the engine fresh device state, made as its own start made it,
+    in the same shapes and shardings: its steps do not compile again."""
+    from repro.serving.cache import init_state
+    with _home(eng.mesh):
+        eng.kv.state = init_state(eng.kv_cfg, sharding=eng.kv.sharding)
+        eng._ssm_states = eng._init_ssm_states()
+
+
+def chip_bytes(tree) -> int:
+    """Bytes of ``tree``'s arrays that one chip holds, by their shardings."""
+    import jax
+    return sum(math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+               for a in jax.tree.leaves(tree))
 
 
 def buckets(max_tokens: int, block: int) -> List[int]:
@@ -229,28 +205,31 @@ def buckets(max_tokens: int, block: int) -> List[int]:
 
 def _lower(eng, kind: str, n_blocks: int, width: int, sharding):
     """Compile ``eng``'s decode or chunk step over a pool of ``n_blocks``
-    blocks: the weights and the pool as the engine places them, every
-    other argument by ``sharding``. Nothing is allocated."""
+    blocks: the weights, the pool and the recurrent state as the engine
+    places them, every other argument by ``sharding``. Nothing is
+    allocated."""
     import jax
     import jax.numpy as jnp
 
     def sds(shape_, dtype, sh=sharding):
         return jax.ShapeDtypeStruct(shape_, dtype, sharding=sh)
 
-    params = jax.tree.map(lambda a: sds(a.shape, a.dtype, a.sharding),
-                          eng.params)
+    def like(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype, a.sharding), tree)
+
+    params, ssm = like(eng.params), like(eng._ssm_states)
     kv = {k: sds((a.shape[0], n_blocks) + tuple(a.shape[2:]), a.dtype,
                  a.sharding) for k, a in eng.kv.state.items()}
     i32, per = jnp.int32, eng.model.n_periods
     if kind == "decode":
         b = eng.max_batch
         return eng._fused_step.lower(
-            params, kv, {}, sds((b,), i32), sds((b,), i32),
+            params, kv, ssm, sds((b,), i32), sds((b,), i32),
             sds((b, width), i32), sds((b,), jnp.bool_),
             sds((per, b), jnp.bool_)).compile()
     cn = eng.prefill_chunk
     return eng._chunk_step.lower(
-        params, kv, {}, sds((1, cn), i32), sds((), i32), sds((), i32),
+        params, kv, ssm, sds((1, cn), i32), sds((), i32), sds((), i32),
         sds((1, width), i32), sds((), i32), sds((per, 1), jnp.bool_)).compile()
 
 
@@ -262,35 +241,41 @@ def plan_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def device_limit(devices) -> int:
+    """The least memory of the cell's chips."""
+    return min(d.memory_stats()["bytes_limit"] for d in devices)
+
+
 def fit_pool(cfg, params, es, width: int, devices, log: Callable,
              mesh=None) -> Tuple[int, dict]:
     """The most blocks whose decode and chunk steps at the widest table,
-    beside the live pool, fit :data:`POOL_SHARE` of each chip's memory.
+    beside the live pool and the resident recurrent state, fit
+    :data:`POOL_SHARE` of each chip's memory.
 
     The chip with the least memory sets the budget. The steps are compiled
-    with the weights and the pool as the engine places them, so their plans
-    (``memory_analysis``) and a block's bytes are what one chip holds. The
-    plans grow linearly with the pool, so two small probes give the line;
-    the pool found is compiled once more to confirm it. Every program here
-    is the same in every run, so the persistent cache holds them after the
-    first."""
-    import jax
+    with the weights, the pool and the recurrent state as the engine
+    places them, so their plans (``memory_analysis``), a block's bytes and
+    the state's are what one chip holds. A plan holds the copies of the
+    pool and the state that warm-up hands each step, beside the live ones
+    counted here. The plans grow linearly with the pool, so two small
+    probes give the line; the pool found is compiled once more to confirm
+    it. Every program here is the same in every run, so the persistent
+    cache holds them after the first."""
     from jax.sharding import NamedSharding, PartitionSpec, \
         SingleDeviceSharding
 
-    limit = min(d.memory_stats()["bytes_limit"] for d in devices)
+    limit = device_limit(devices)
     budget = POOL_SHARE * limit
     probe = build_engine(cfg, params, es, 2 * es["max_batch"], mesh)
-    per_block = sum(math.prod(a.sharding.shard_shape(a.shape))
-                    * a.dtype.itemsize for a in jax.tree.leaves(
-                        probe.kv.state)) // probe.kv_cfg.n_blocks
+    per_block = chip_bytes(probe.kv.state) // probe.kv_cfg.n_blocks
+    state = chip_bytes(probe._ssm_states)
     shard = SingleDeviceSharding(devices[0]) if mesh is None else \
         NamedSharding(mesh, PartitionSpec())
 
     def need(n):
         plans = {k: plan_bytes(_lower(probe, k, n, width, shard))
                  for k in ("decode", "chunk")}
-        return max(plans.values()) + per_block * n, plans
+        return max(plans.values()) + per_block * n + state, plans
 
     try:
         lo, hi = 256, 512
@@ -303,11 +288,10 @@ def fit_pool(cfg, params, es, width: int, devices, log: Callable,
                 break
             n -= 64
     finally:
-        for x in probe.kv.state.values():
-            x.delete()
+        free_state(probe)
     if n <= 0:
         raise RuntimeError(f"no pool fits {devices}")
-    info = {"blocks": n, "pool_bytes": per_block * n,
+    info = {"blocks": n, "pool_bytes": per_block * n, "state_bytes": state,
             "decode_plan_bytes": plans["decode"],
             "chunk_plan_bytes": plans["chunk"], "budget_bytes": int(budget),
             "device_limit_bytes": limit}

@@ -1,26 +1,20 @@
-"""The plain reference: a float32 forward of a dense decoder, written from
-the published architecture and the configuration file, importing nothing
-of the program.
+"""What every family's plain reference shares (bench/families/): float32
+arithmetic written from the published architectures, importing nothing of
+the program.
 
-Pre-norm blocks: RMSNorm, q/k/v projections (with biases where the config
-has them), rotary embedding (rotate-half, all of head_dim), causal
-grouped-query attention at 1/sqrt(head_dim), output projection; RMSNorm,
-SwiGLU MLP. Final RMSNorm and the head, over the real vocabulary: the
-untied output projection where the weights have one (``head``, (d,
-vocab)), else the tied embedding. Every matmul is float32 at
-``Precision.HIGHEST``; the bf16 weights are widened one layer at a time,
-and attention and the head run in blocks of rows, so a long sequence fits
-beside the weights. Weights sharded over several chips stay where they
-are: the forward runs on those chips.
+Every matmul is float32 at ``Precision.HIGHEST``; attention and the head
+run in blocks of :data:`ROWS` rows, so a long sequence fits beside the
+weights. Weights sharded over several chips stay where they are: the
+forward runs on those chips. The control computes one precision below the
+configurations' bf16: every matmul operand rounded to float8 e4m3
+(:func:`fp8`, activations per row, weights per tensor).
 
-:func:`make` returns a jitted function of (weights, tokens) that gives, at
-each position, the largest reference logit, the row's standard deviation,
-the reference logit of the next token, and, for the control, the
-reference logit of the token a lower-precision forward puts first.
+:func:`readings` turns a family's forward (to the final normed hidden
+states) into the jitted function of (weights, tokens) that the comparison
+(bench/correct.py) and the control (bench/control.py) read.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -39,21 +33,22 @@ def fp8(x, axis=None):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _ident(x, axis=None):
+def ident(x, axis=None):
     return x
 
 
-def _mm(eq, a, w, lowp):
+def mm(eq, a, w, lowp):
     """A matmul; under the control both operands are rounded to e4m3
     (activations per row, weights per tensor)."""
     return jnp.einsum(eq, lowp(a, axis=-1), lowp(w), precision=HI)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
+def rope(x, theta):
+    """Rotary embedding (rotate-half, all of head_dim) of x (S, H, hd)."""
     s, _, hd = x.shape
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -62,9 +57,9 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v):
-    """Causal attention of q (S, H, hd) over k, v (S, K, hd), in blocks of
-    query rows."""
+def attention(q, k, v):
+    """Causal grouped-query attention of q (S, H, hd) over k, v (S, K, hd)
+    at 1/sqrt(hd), in blocks of query rows."""
     s, h, hd = q.shape
     kh = k.shape[1]
     qg = q.reshape(s // ROWS, ROWS, kh, h // kh, hd)
@@ -83,38 +78,17 @@ def _attention(q, k, v):
     return out.reshape(s, h, hd)
 
 
-def forward_hidden(conf, w, tokens, lowp=_ident):
-    """Final normed hidden states (S, d), float32."""
-    eps, theta = float(conf["rms_norm_eps"]), float(conf["rope_theta"])
-    f32 = functools.partial(jax.tree.map, lambda a: a.astype(jnp.float32))
-    x = w["embed"][tokens].astype(jnp.float32)
-
-    def layer(x, lw):
-        a, m = f32(lw["mix"]), f32(lw["ffn"])
-        h = _rms(x, a["ln"], eps)
-        q = _mm("sd,dhk->shk", h, a["wq"], lowp)
-        k = _mm("sd,dhk->shk", h, a["wk"], lowp)
-        v = _mm("sd,dhk->shk", h, a["wv"], lowp)
-        if "bq" in a:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        o = _attention(_rope(q, theta), _rope(k, theta), v)
-        x = x + _mm("shk,hkd->sd", o, a["wo"], lowp)
-        h = _rms(x, m["ln"], eps)
-        g = _mm("sd,df->sf", h, m["w_gate"], lowp)
-        u = _mm("sd,df->sf", h, m["w_up"], lowp)
-        x = x + _mm("sf,fd->sd", jax.nn.silu(g) * u, m["w_down"], lowp)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, w["blocks"]["pos0"])
-    return _rms(x, w["final_ln"].astype(jnp.float32), eps)
-
-
-def make(conf, control: bool = False):
+def readings(conf, hidden, control: bool = False):
     """Jitted ``f(weights, tokens)`` -> dict of per-position float32
     vectors: ``max`` and ``sd`` of the reference logits, ``next`` the
     reference logit of ``tokens[i + 1]``; with ``control`` also ``pick``,
     the reference logit of the token the e4m3 forward ranks first.
-    ``tokens`` is (S,) with S a multiple of :data:`ROWS`."""
+
+    ``hidden(w, tokens, lowp)`` is the family's forward to the final normed
+    hidden states (S, d), float32, every matmul through :func:`mm` with
+    ``lowp``. The head is the untied output projection where the weights
+    have one (``head``, (d, vocab)), else the tied embedding, over the real
+    vocabulary. ``tokens`` is (S,) with S a multiple of :data:`ROWS`."""
     vocab = int(conf["vocab_size"])
 
     def f(w, tokens):
@@ -122,9 +96,9 @@ def make(conf, control: bool = False):
             out_w, eq = w["head"][:, :vocab].astype(jnp.float32), "rd,dv->rv"
         else:
             out_w, eq = w["embed"][:vocab].astype(jnp.float32), "rd,vd->rv"
-        h = forward_hidden(conf, w, tokens)
+        h = hidden(w, tokens, ident)
         nxt = jnp.concatenate([tokens[1:], tokens[:1]])
-        hl = forward_hidden(conf, w, tokens, fp8) if control else h
+        hl = hidden(w, tokens, fp8) if control else h
         s = tokens.shape[0]
 
         def block(args):
@@ -133,7 +107,7 @@ def make(conf, control: bool = False):
             out = {"max": lg.max(-1), "sd": lg.std(-1),
                    "next": jnp.take_along_axis(lg, nb[:, None], 1)[:, 0]}
             if control:
-                lo = _mm(eq, hlb, out_w, fp8)
+                lo = mm(eq, hlb, out_w, fp8)
                 pick = jnp.argmax(lo, -1)
                 out["pick"] = jnp.take_along_axis(lg, pick[:, None], 1)[:, 0]
             return out

@@ -4,20 +4,24 @@
         --seconds 30 --trace 0
 
 The cell (``BENCHMARK.json`` ``workloads``) names a model configuration
-(``bench/configs/``) and a traffic mix (``bench/traffic/``). One process:
+(``bench/configs/``), whose family (bench/families/) builds and counts the
+model and gives its reference, and a traffic mix (``bench/traffic/``).
+One process:
 
 1. turns on JAX's persistent compilation cache at ``.jax_cache/`` in the
    checkout (or where ``JAX_COMPILATION_CACHE_DIR`` says);
 2. finds the chips the cell asks for, or exits non-zero with no result;
 3. makes the weights from ``--seed`` on the device (bench/model.py);
-4. fits the KV pool to the memory the decode and chunk steps leave;
+4. fits the KV pool to the memory the decode and chunk steps and the
+   recurrent state leave;
 5. compiles the decode and chunk steps at every block-table width the
    cell's lengths can reach, and no others;
 6. ramps the traffic for the mix's ``ramp_s``, then measures ``--seconds``
    (bench/driver.py), with the profiler over the window's last seconds
    when ``--trace 1``;
-7. reads peak device memory, frees the KV pool, and holds a sample of the
-   finished requests to the float32 reference (bench/correct.py);
+7. reads peak device memory, frees the engine's device state, and holds a
+   sample of the finished requests to the family's float32 reference
+   (bench/correct.py);
 8. prints the numbers compared beside their limits on standard error,
    and one JSON line on standard output: ``correct``, ``attempted``,
    ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -53,7 +57,6 @@ CACHE_DIR = REPO / ".jax_cache"
 # and the sharded steps run some 60k ops a step a chip (the XLA paged read),
 # so on four v5e chips 1 s of trace took 95 s to stop (224 MB)
 TRACE_S, TRACE_S_SHARDED = 4.0, 0.5
-KERNEL = "tpu_custom_call"  # the paged read: the one Pallas kernel of the step
 
 
 class NoChip(RuntimeError):
@@ -123,7 +126,7 @@ class Context:
     """What the per-layer readers see (bench/layer_metrics)."""
     run: object
     es: dict
-    shape: object
+    counts: object
     peaks: dict
     chips: int
     trace: Optional[dict]
@@ -176,10 +179,10 @@ def prepare(conf, mix, seed: int, devices, *, clock=time.monotonic,
     ``devices``, and each weight is drawn into the sharding it serves in."""
     import jax
 
-    from bench import model, traffic
+    from bench import families, model, traffic
 
     es = conf["engine"]
-    cfg = model.arch_config(conf)
+    cfg = families.load(conf).arch_config(conf)
     mesh = model.make_mesh(devices) if len(devices) > 1 else None
     shardings = None if mesh is None else model.param_shardings(cfg, mesh)
     params = model.make_weights(conf, seed, shardings)
@@ -207,13 +210,14 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
              clock: Callable[[], float] = time.monotonic, out=log) -> dict:
     import jax
 
-    from bench import correct, driver, model, reference, trace_reduce, \
+    from bench import correct, driver, families, model, trace_reduce, \
         traffic, window
     from bench.layer_metrics import load as load_metric
 
     prep = prepare(conf, mix, seed, devices, clock=clock, out=out)
     eng, params, es, cfg = prep.eng, prep.params, prep.es, prep.cfg
-    shape, n_blocks = model.shape(conf, es), prep.n_blocks
+    family = families.load(conf)
+    counts, n_blocks = family.counts(conf, es), prep.n_blocks
     plan = traffic.plan(mix, seed, seconds, cfg.vocab_size)
     counter = CompileCounter()
 
@@ -244,7 +248,7 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
         t = clock()
         path = trace_reduce.find(tdir)
         size = os.path.getsize(path)
-        reduced = trace_reduce.read(path, KERNEL)
+        reduced = trace_reduce.read(path)
         shutil.rmtree(tdir, ignore_errors=True)
         out(f"trace: {size} bytes, {len(reduced['ops'])} op names, read in "
             f"{clock() - t:.1f} s")
@@ -252,7 +256,7 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
     due = [tr for tr in run.tracked if run.t0 <= tr.due < run.t1]
     failed = sum(tr.state in ("failed", "rejected", "timed_out", "cancelled")
                  for tr in due)
-    ctx = Context(run, es, shape, peaks, len(devices), reduced, counter.n,
+    ctx = Context(run, es, counts, peaks, len(devices), reduced, counter.n,
                   peak, n_blocks)
     win = [s for s in run.steps if run.t0 <= s.t0 < run.t1]
     slow = max(win, key=lambda s: s.t1 - s.t0, default=None)
@@ -277,13 +281,12 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
                    for m in bench["end_to_end"]
                    if "workloads" not in m or wl["name"] in m["workloads"]}
     # free the program's state before the reference runs
-    for a in eng.kv.state.values():
-        a.delete()
+    model.free_state(eng)
     picked = correct.sample(run, seed)
     del eng, prep.eng
     gc.collect()
 
-    fn = reference.make(conf)
+    fn = family.reference(conf)
     readings = correct.compare(fn, params, picked,
                                correct.padded(traffic.max_context(mix)))
     verdict = correct.verdict(readings, limits)
@@ -304,7 +307,8 @@ def run_cell(bench, wl, conf, mix, *, seed: int, seconds: float, trace: bool,
             "device_ops": [[k, v] for k, v in ops],
             "idle_gaps": [[k, v] for k, v in reduced["gaps"][:10]]}
         out(f"idle by host span: {reduced['idle_by_span']}")
-        out(f"kernel: {reduced['kernel_calls']} events, {reduced['kernel_s']} s")
+        out(f"kernels: {reduced['kernel_calls']} events, "
+            f"{reduced['kernel_s']} s: {reduced['kernels']}")
         out(f"collectives: {reduced['collective_s']} s a chip; " + str({
             k[:48]: v for k, v in reduced["ops"].items()
             if trace_reduce.COLLECTIVE.match(k)}))

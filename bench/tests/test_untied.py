@@ -22,17 +22,18 @@ def untied_conf():
 def test_untied_layout_matches_the_model():
     import jax
     from bench import model
+    from bench.families import dense
 
     conf = untied_conf()
-    lay = model.layout(conf)
+    lay = dense.layout(conf)
     assert lay["head"][0] == (128, 512)
     params = model.make_weights(conf, SEED)
-    model.check_layout(model.arch_config(conf), params)
+    model.check_layout(dense.arch_config(conf), params)
     head = np.asarray(params["head"], np.float32)
     assert not head[:, 500:].any() and head[:, :500].any()
     # the tied configuration's draw is untouched by the head's leaf
     tied = dict(conf, tie_word_embeddings=True)
-    assert "head" not in model.layout(tied)
+    assert "head" not in dense.layout(tied)
     a = model.make_weights(tied, SEED)
     assert all(np.array_equal(np.asarray(a["blocks"]["pos0"]["mix"][k]),
                               np.asarray(params["blocks"]["pos0"]["mix"][k]))
@@ -44,17 +45,18 @@ def test_reference_matches_the_program_forward_at_float32():
     import jax
     import jax.numpy as jnp
     from bench import model, reference
+    from bench.families import dense
     from repro.models.lm import LM
 
     conf = untied_conf()
     params = model.make_weights(conf, SEED)
     tokens = np.random.default_rng(SEED).integers(0, 500, reference.ROWS)
-    ref = {k: np.asarray(v) for k, v in reference.make(conf)(
+    ref = {k: np.asarray(v) for k, v in dense.reference(conf)(
         params, jnp.asarray(tokens, jnp.int32)).items()}
 
     f32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     with jax.default_matmul_precision("highest"):
-        logits = LM(model.arch_config(conf)).forward(
+        logits = LM(dense.arch_config(conf)).forward(
             f32, {"tokens": jnp.asarray(tokens, jnp.int32)[None]})
     lg = np.asarray(logits, np.float64)[0, :, :500]
     nxt = np.concatenate([tokens[1:], tokens[:1]])

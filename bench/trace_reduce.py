@@ -1,7 +1,8 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to device busy time,
-time per device operation, kernel time, collective time, and idle gaps
-named by the host span (``bench.*``) that covers most of each gap. Every
-time is seconds of one chip: the mean over the devices in the trace.
+time per device operation, kernel time, in all and by each Pallas
+kernel's own name, collective time, and idle gaps named by the host span
+(``bench.*``) that covers most of each gap. Every time is seconds of one
+chip: the mean over the devices in the trace.
 
 Device planes are those named ``/device:TPU:<n>``; their operations are
 the events on the line named ``XLA Ops``. Host spans are the events named
@@ -23,6 +24,8 @@ Interval = Tuple[float, float]
 
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench."
+# the custom call that every Pallas kernel on a TPU is
+PALLAS = "tpu_custom_call"
 # the exchanges between chips, whole or as their async start/done halves.
 # A TPU trace names an op by its HLO text ("%all-reduce.5 = bf16[...]
 # all-reduce(...), ..."): the match is on the instruction's own name.
@@ -92,6 +95,13 @@ def merge(a: np.ndarray, b: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return a[i], reach[np.append(i[1:] - 1, len(a) - 1)]
 
 
+def kernel_name(op: str) -> str:
+    """A kernel's own name (its ``pallas_call``'s ``name``) from its op's
+    name: the HLO instruction's name (``%paged_decode.5 = ...``) less its
+    ``%`` and its number."""
+    return re.sub(r"\.\d+$", "", op.split(" = ", 1)[0].lstrip("%"))
+
+
 def kernel_by_name(name: str, kernel: str) -> Optional[bool]:
     """Whether an op named ``name`` is the kernel, where the name decides:
     it names ``kernel``, or it is a whole HLO instruction (``%x = ...
@@ -106,7 +116,8 @@ def kernel_by_name(name: str, kernel: str) -> Optional[bool]:
 
 class Device:
     """One device's ops, reduced as they stream in (``add_line``): seconds per
-    op name, kernel seconds and calls, the busy and collective intervals."""
+    op name, kernel seconds and calls in all and by kernel name, the busy
+    and collective intervals."""
 
     def __init__(self, kernel: str):
         self.kernel = kernel
@@ -114,11 +125,13 @@ class Device:
         self.ca, self.cb = array("d"), array("d")
         self.ops: Dict[str, float] = defaultdict(float)
         self.kernel_s, self.kernel_n = 0.0, 0
-        self._kind: Dict[str, Tuple[bool, Optional[bool]]] = {}
+        self.kernels: Dict[str, List] = {}     # name: [seconds, calls]
+        self._kind: Dict[str, Tuple[bool, Optional[bool], str]] = {}
 
     def add_line(self, events) -> None:
         """Every event of one ``XLA Ops`` line, read once: a name's
-        collective match and kernel match are worked out once per name, and
+        collective match, kernel match and kernel name are worked out once
+        per name, and
         an event's stats are read only where its name does not decide."""
         kernel, kind, ops = self.kernel, self._kind, self.ops
         add_a, add_b = self.a.append, self.b.append
@@ -130,7 +143,8 @@ class Device:
             k = kind.get(name)
             if k is None:
                 k = kind[name] = (bool(COLLECTIVE.match(name)),
-                                  kernel_by_name(name, kernel))
+                                  kernel_by_name(name, kernel),
+                                  kernel_name(name))
             if k[0]:
                 self.ca.append(a)
                 self.cb.append(b)
@@ -140,6 +154,9 @@ class Device:
             if hit:
                 self.kernel_s += (b - a) * 1e-9
                 self.kernel_n += 1
+                per = self.kernels.setdefault(k[2], [0.0, 0])
+                per[0] += (b - a) * 1e-9
+                per[1] += 1
 
 
 def name_gaps(ga: np.ndarray, gb: np.ndarray,
@@ -175,13 +192,15 @@ def reduce_devices(devices: Dict[str, Device],
     """``devices``: each device's ops, all inside ``window`` (ns);
     ``spans``: host (start_ns, end_ns, name). Returns seconds per chip
     (the mean over devices): busy, per-op, kernel time, the time in which
-    a collective ran (``collective_s``); the kernel's calls over all
-    devices, the ``top`` longest idle gaps named by host span, and the
+    a collective ran (``collective_s``); the kernels' calls over all
+    devices; ``kernels``, each kernel's own seconds (``s``) and calls by
+    its name; the ``top`` longest idle gaps named by host span, and the
     idle seconds under each host span."""
     spans = sorted(spans)
     lo, hi = window
     busy_s, ops, gaps = [], defaultdict(float), []
     kernel_s, kernel_n, collective_s = 0.0, 0, 0.0
+    kernels: Dict[str, Dict] = {}
     idle_by_span = defaultdict(float)
     n = max(len(devices), 1)
     for _, d in sorted(devices.items()):
@@ -193,6 +212,10 @@ def reduce_devices(devices: Dict[str, Device],
             ops[k] += v
         kernel_s += d.kernel_s
         kernel_n += d.kernel_n
+        for k, (sec, calls) in d.kernels.items():
+            kk = kernels.setdefault(k, {"s": 0.0, "calls": 0})
+            kk["s"] += sec
+            kk["calls"] += calls
         ga, gb = np.append(lo, e), np.append(s, hi)
         keep = gb > ga
         ga, gb = ga[keep], gb[keep]
@@ -205,14 +228,17 @@ def reduce_devices(devices: Dict[str, Device],
     return {"busy_s": sum(busy_s) / n, "window_s": (hi - lo) * 1e-9,
             "ops": {k: v / n for k, v in ops.items()},
             "kernel_s": kernel_s / n, "kernel_calls": kernel_n,
+            "kernels": {k: {"s": v["s"] / n, "calls": v["calls"]}
+                        for k, v in kernels.items()},
             "collective_s": collective_s / n,
             "gaps": sorted(gaps, key=lambda g: -g[1])[:top],
             "idle_by_span": dict(idle_by_span), "devices": len(busy_s)}
 
 
-def read(path: str, kernel: str) -> dict:
+def read(path: str, kernel: str = PALLAS) -> dict:
     """Load ``path`` and reduce it over the span of every event in the
-    trace, one device plane at a time."""
+    trace, one device plane at a time. ``kernel`` marks the ops that are
+    kernels, in an op's name or in its stats."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
